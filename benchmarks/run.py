@@ -64,6 +64,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_corpus, bench_crossover,
                             bench_dense_limit, bench_footprint, bench_fused,
                             bench_sddmm, bench_serve, bench_serve_fleet,

@@ -2,7 +2,7 @@
 
 Trains a 3-layer GCN (hidden 128, feature dim 256 — the paper's §4.1
 setting) and a GAT (SDDMM attention with d=2 per §4.4) on a synthetic
-random graph, full-batch, on CPU.
+random graph, full-batch, on whatever backend JAX finds.
 
 Usage:  PYTHONPATH=src python examples/gnn_train.py [--kind gat] [--n 512]
 """
@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.paper_gnn import CONFIG as GCFG
 from repro.data.pipeline import random_graph
 from repro.models.gnn import (build_graph, gat_forward, gcn_forward,
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--lr", type=float, default=0.05)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     adj = random_graph(args.n, avg_degree=8, seed=1)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -31,7 +32,7 @@ from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
 from repro.dispatch.dispatcher import plan_spmm
 from repro.dispatch.policy import PATH_CSR, PATH_ELL
 from repro.resilience import chaos
-from repro.resilience.errors import TRANSIENT, classify
+from repro.resilience.errors import TRANSIENT, CompileError, classify
 from repro.sparse import paths
 from repro.sparse.matrix import SparseMatrix
 from repro.batch.block_diag import BatchedSparseMatrix
@@ -238,15 +239,33 @@ class BucketedExecutor:
 
         lane = self.lane_label(key)
         if self.jit:
+            traced = threading.local()
+
             def run(*args):
                 # trace-time chaos first, so an injected compile failure
                 # does not pollute the compile counters or the sentry
                 chaos.hook("executor.compile", lane=lane)
                 self.compiles += 1  # runs at trace time only
                 obs.SENTRY.record_compile(lane)
-                return body(*args)
+                out = body(*args)
+                traced.done = True
+                return out
 
-            exe = jax.jit(run)
+            jitted = jax.jit(run)
+
+            def exe(*args):
+                # an error after this call's trace finished came from
+                # lowering or compiling (a refused kernel): surface it as
+                # a CompileError, never as a request's or a form's fault
+                traced.done = False
+                try:
+                    return jitted(*args)
+                except Exception as exc:
+                    if getattr(traced, "done", False):
+                        raise CompileError(
+                            f"executor {lane}: the traced program failed "
+                            f"to compile: {exc}") from exc
+                    raise
         else:
             self.compiles += 1  # eager mode: one "trace" per key
             obs.SENTRY.record_compile(lane)

@@ -23,14 +23,14 @@ row buffering (§3.1.3).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.formats import BlockELL
+from repro.dispatch.dispatcher import default_use_kernel
 from repro.kernels.spmm.ops import spmm_blockell
 
 
@@ -62,50 +62,54 @@ def _ell_specs(ell: BlockELL, row_axis) -> BlockELL:
 
 
 def spmm_1p5d(ell, h, mesh: Mesh, *, row_axis: str = "data",
-              use_kernel: bool = False):
+              use_kernel: Optional[bool] = None):
     """1.5D: A row-sharded, H row-sharded + all-gathered per step.
 
     ``ell``: BlockELL or ``repro.sparse.SparseMatrix``.
     """
     ell = _as_blockell(ell)
+    if use_kernel is None:
+        use_kernel = default_use_kernel()
 
     def local(ell_shard: BlockELL, h_shard):
         h_full = jax.lax.all_gather(h_shard, row_axis, axis=0, tiled=True)
         return spmm_blockell(ell_shard, h_full, use_kernel=use_kernel)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_ell_specs(ell, row_axis), P(row_axis, None)),
         out_specs=P(row_axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(ell, h)
 
 
 def spmm_2d(ell, h, mesh: Mesh, *, row_axis: str = "data",
-            col_axis: str = "model", use_kernel: bool = False):
+            col_axis: str = "model", use_kernel: Optional[bool] = None):
     """2D: A row-sharded over data, H column-sharded over model; no comm.
 
     ``ell``: BlockELL or ``repro.sparse.SparseMatrix``.
     """
     ell = _as_blockell(ell)
+    if use_kernel is None:
+        use_kernel = default_use_kernel()
 
     def local(ell_shard: BlockELL, h_shard):
         return spmm_blockell(ell_shard, h_shard, use_kernel=use_kernel)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_ell_specs(ell, row_axis), P(None, col_axis)),
         out_specs=P(row_axis, col_axis),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(ell, h)
 
 
 def spmm_2p5d(ell, h, mesh: Mesh, *, pod_axis: str = "pod",
-              row_axis: str = "data", use_kernel: bool = False):
+              row_axis: str = "data", use_kernel: Optional[bool] = None):
     """2.5D multi-pod: H replicated across pods; all-gather intra-pod only.
 
     A's block-rows are sharded over (pod, data) jointly; each pod computes
@@ -114,12 +118,14 @@ def spmm_2p5d(ell, h, mesh: Mesh, *, pod_axis: str = "pod",
     ``ell``: BlockELL or ``repro.sparse.SparseMatrix``.
     """
     ell = _as_blockell(ell)
+    if use_kernel is None:
+        use_kernel = default_use_kernel()
 
     def local(ell_shard: BlockELL, h_shard):
         h_full = jax.lax.all_gather(h_shard, row_axis, axis=0, tiled=True)
         return spmm_blockell(ell_shard, h_full, use_kernel=use_kernel)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -127,7 +133,7 @@ def spmm_2p5d(ell, h, mesh: Mesh, *, pod_axis: str = "pod",
             P(row_axis, None),  # H row-sharded over data, replicated on pod
         ),
         out_specs=P((pod_axis, row_axis), None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(ell, h)
 
@@ -168,11 +174,11 @@ def allgather_matmul_overlap(x, w, mesh: Mesh, *, axis: str = "model"):
         (acc, _), _ = jax.lax.scan(step, (acc0, w_shard), jnp.arange(n))
         return acc
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axis, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)

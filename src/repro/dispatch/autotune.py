@@ -136,22 +136,12 @@ def measure(candidates: Dict[str, Callable[[], object]], *,
             warmup: int = 1, iters: int = 3) -> Measurement:
     """Time each candidate thunk; return the winner + all timings.
 
-    Candidates that raise are recorded as +inf (a path can legitimately
-    be unavailable, e.g. the Pallas kernel on an unsupported shape).
+    A candidate that raises propagates: a kernel the compiler refuses is
+    a fault to fix, not a path that quietly loses the race.
     """
-    timings: Dict[str, float] = {}
-    last_exc: Optional[Exception] = None
-    for name, thunk in candidates.items():
-        try:
-            timings[name] = _time_us(thunk, warmup, iters)
-        except Exception as exc:  # noqa: BLE001 - unavailable path, not fatal
-            timings[name] = float("inf")
-            last_exc = exc
-    finite = {p: t for p, t in timings.items() if t != float("inf")}
-    if not finite:
-        raise RuntimeError(
-            "autotune: every candidate path failed") from last_exc
-    best = min(finite, key=finite.get)
+    timings = {name: _time_us(thunk, warmup, iters)
+               for name, thunk in candidates.items()}
+    best = min(timings, key=timings.get)
     return Measurement(path=best, timings_us=timings)
 
 
@@ -183,17 +173,17 @@ def calibrate(
     """
     import numpy as np
 
-    import jax
     import jax.numpy as jnp
 
     from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
+    from repro.dispatch.dispatcher import default_use_kernel
     from repro.sparse import SparseMatrix, autodiff
 
     rng = np.random.default_rng(seed)
     h = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     # time what the dispatcher would actually run on this backend: the
     # Pallas kernels on TPU, the jnp references elsewhere
-    use_kernel = jax.default_backend() == "tpu"
+    use_kernel = default_use_kernel()
     ratios: Dict[str, list] = {"ell": [], "sell": [], "csr": []}
     for density in densities:
         dense = np.where(rng.random((n, n)) < density,
@@ -205,18 +195,14 @@ def calibrate(
                 (p, use_kernel, False, None, None), a, h))
             for p in ("ell", "sell", "csr", "dense")
         }
-        m = measure(thunks, warmup=warmup, iters=iters)
-        t = m.timings_us
-        if t.get("dense", float("inf")) == float("inf"):
-            continue
+        t = measure(thunks, warmup=warmup, iters=iters).timings_us
         per_dense = t["dense"] / max(stats.dense_elements * d, 1)
         streamed = {"ell": stats.stored_elements,
                     "sell": stats.sell_stored_elements,
                     "csr": stats.nnz}
         for p, vol in streamed.items():
-            tp = t.get(p, float("inf"))
-            if tp != float("inf") and vol > 0 and per_dense > 0:
-                ratios[p].append((tp / (vol * d)) / per_dense)
+            if vol > 0 and per_dense > 0:
+                ratios[p].append((t[p] / (vol * d)) / per_dense)
 
     def _tuned(path: str, shipped: float) -> float:
         if not ratios[path]:

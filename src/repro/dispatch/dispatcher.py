@@ -154,7 +154,9 @@ def _is_traced(*arrays) -> bool:
     return any(isinstance(a, jax.core.Tracer) for a in arrays)
 
 
-def _default_use_kernel(config: DispatchConfig) -> bool:
+def default_use_kernel(config: DispatchConfig = DEFAULT_CONFIG) -> bool:
+    """The Pallas kernel on TPU, the jnp reference elsewhere, unless the
+    config forces one (the one backend rule every sparse path follows)."""
     if config.use_kernel is not None:
         return config.use_kernel
     return jax.default_backend() == "tpu"
@@ -265,7 +267,7 @@ def _plan(op, costs, stats, *, policy, config, use_kernel, interpret,
     if candidates:
         costs = {p: c for p, c in costs.items() if p in candidates}
     uk = use_kernel if use_kernel is not None \
-        else _default_use_kernel(config)
+        else default_use_kernel(config)
     if policy in (PATH_ELL, PATH_SELL, PATH_CSR, PATH_DENSE):
         if candidates and policy not in candidates:
             raise ValueError(
@@ -377,7 +379,7 @@ def dispatch_spmm(
                 "operand data, but the BlockELL is traced (inside jit); "
                 "dispatch outside jit or use the ell path")
         uk = use_kernel if use_kernel is not None \
-            else _default_use_kernel(config)
+            else default_use_kernel(config)
         _record(Plan(op="spmm", path=PATH_ELL, policy=policy,
                      reason="traced operand: blocked path only",
                      use_kernel=uk, interpret=interpret))
@@ -389,7 +391,7 @@ def dispatch_spmm(
     if policy in (PATH_ELL, PATH_CSR, PATH_DENSE):
         # forced path: no stats needed (skips the host nonzero count)
         uk = use_kernel if use_kernel is not None \
-            else _default_use_kernel(config)
+            else default_use_kernel(config)
         plan = Plan(op="spmm", path=policy, policy=policy, reason="forced",
                     use_kernel=uk, interpret=interpret)
         _record(plan)
@@ -405,7 +407,7 @@ def dispatch_spmm(
         key = make_key("spmm", stats.shape, d, h.dtype, stats.density,
                        buckets_per_decade=config.buckets_per_decade)
         uk = use_kernel if use_kernel is not None \
-            else _default_use_kernel(config)
+            else default_use_kernel(config)
         hit = cache.get(key)
         if hit is None:
             candidates = {
@@ -540,7 +542,7 @@ def dispatch_sddmm(
             .at[:, : c.shape[1]].set(c)
 
     traced = _is_traced(a.blocks, a.rows, a.cols)
-    uk = use_kernel if use_kernel is not None else _default_use_kernel(config)
+    uk = use_kernel if use_kernel is not None else default_use_kernel(config)
     if traced:  # blocked path is the only tracer-safe one
         if policy in (PATH_SELL, PATH_CSR, PATH_DENSE):
             raise TypeError(

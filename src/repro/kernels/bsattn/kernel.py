@@ -26,7 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -142,7 +141,7 @@ def bsattn_kernel(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

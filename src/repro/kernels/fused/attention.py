@@ -20,6 +20,12 @@ accumulation) advance together, with the ``scale`` factor retroactively
 correcting earlier tiles — algebraically identical to two passes over
 the row, matching ``models.gnn._segment_softmax`` to float tolerance.
 
+The kernels take the key factor N-major (``k``: [N, dk]) and contract
+it on its minor axis, so a score tile reads a (bn, dk) slab: a (dk, bn)
+tile of kᵀ would put bn = 64 on the minor axis, which the TPU lowering
+refuses.  The wrappers keep the [dk, N] ``kt`` interface of the
+references and transpose once.
+
 Layouts:
   * Block-ELL — grid (nbr, W), W innermost; the structural mask comes
     from A's blocks (padding slots are all-zero and mask out).
@@ -44,7 +50,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BlockELL, SellCS
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.fused.epilogue import apply_act
 
 NEG_INF = -1e30   # finite: masked - masked stays nan-free
@@ -56,7 +61,7 @@ EPS = 1e-12       # the _segment_softmax denominator guard
 # ---------------------------------------------------------------------------
 
 
-def _ell_attn_kernel(idx_ref, a_ref, q_ref, kt_ref, v_ref, o_ref,
+def _ell_attn_kernel(idx_ref, a_ref, q_ref, k_ref, v_ref, o_ref,
                      acc_ref, m_ref, l_ref, *, n_slots: int, act: str,
                      slope: float):
     w = pl.program_id(1)
@@ -69,8 +74,8 @@ def _ell_attn_kernel(idx_ref, a_ref, q_ref, kt_ref, v_ref, o_ref,
 
     mask = a_ref[0, 0, :, :] != 0
     s = jax.lax.dot_general(
-        q_ref[...], kt_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        q_ref[...], k_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [bm, bn]
     s = jnp.where(mask, apply_act(s, act, slope), NEG_INF)
@@ -100,7 +105,7 @@ def fused_attn_blockell_kernel(
     indices,  # int32[nbr, W]
     blocks,  # dtype[nbr, W, bm, bn]  structural mask source
     q,  # dtype[nbr*bm, dk]
-    kt,  # dtype[dk, Np]
+    k,  # dtype[Np, dk]
     v,  # dtype[Np, D]
     *,
     act: str = "leaky_relu",
@@ -126,7 +131,7 @@ def fused_attn_blockell_kernel(
                 pl.BlockSpec((1, 1, bm, bn),
                              lambda i, s, idx: (i, s, 0, 0)),
                 pl.BlockSpec((bm, dk), lambda i, s, idx: (i, 0)),
-                pl.BlockSpec((dk, bn), lambda i, s, idx: (0, idx[i, s])),
+                pl.BlockSpec((bn, dk), lambda i, s, idx: (idx[i, s], 0)),
                 pl.BlockSpec((bn, d), lambda i, s, idx: (idx[i, s], 0)),
             ],
             out_specs=pl.BlockSpec((bm, d), lambda i, s, idx: (i, 0)),
@@ -137,12 +142,12 @@ def fused_attn_blockell_kernel(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((mp, d), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="fused_graph_attention_blockell",
-    )(indices, blocks, q, kt, v)
+    )(indices, blocks, q, k, v)
 
 
 def fused_attn_blockell_ref(ell: BlockELL, q, kt, v, *,
@@ -192,7 +197,7 @@ def fused_attn_blockell(ell: BlockELL, q, kt, v, *,
         v = jnp.zeros((np_, d), v.dtype).at[: v.shape[0]].set(v)
     if use_kernel or interpret:
         return fused_attn_blockell_kernel(
-            ell.indices, ell.blocks, q, kt, v, act=act, slope=slope,
+            ell.indices, ell.blocks, q, kt.T, v, act=act, slope=slope,
             out_dtype=out_dtype, interpret=interpret)
     return fused_attn_blockell_ref(ell, q, kt, v, act=act, slope=slope,
                                    out_dtype=out_dtype)
@@ -233,7 +238,7 @@ def fused_attn_blockcoo_ref(coo, q, kt, v, *, act: str = "leaky_relu",
 # ---------------------------------------------------------------------------
 
 
-def _sell_attn_kernel(rows_ref, cols_ref, mask_ref, q_ref, kt_ref, v_ref,
+def _sell_attn_kernel(rows_ref, cols_ref, mask_ref, q_ref, k_ref, v_ref,
                       o_ref, acc_ref, m_ref, l_ref, *, n_tiles: int,
                       act: str, slope: float):
     t = pl.program_id(0)
@@ -249,8 +254,8 @@ def _sell_attn_kernel(rows_ref, cols_ref, mask_ref, q_ref, kt_ref, v_ref,
 
     mask = mask_ref[0, :, :] != 0
     s = jax.lax.dot_general(
-        q_ref[...], kt_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        q_ref[...], k_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     s = jnp.where(mask, apply_act(s, act, slope), NEG_INF)
@@ -283,7 +288,7 @@ def fused_attn_sell_kernel(
     tile_cols,  # int32[T]
     mask_blocks,  # dtype[T, bm, bn]  0/1 structural pattern
     q_perm,  # dtype[n_live*bm, dk]  q gathered into packed row order
-    kt,  # dtype[dk, Np]
+    k,  # dtype[Np, dk]
     v,  # dtype[Np, D]
     *,
     n_live_block_rows: int,
@@ -310,7 +315,7 @@ def fused_attn_sell_kernel(
                 pl.BlockSpec((1, bm, bn),
                              lambda t, rows, cols: (t, 0, 0)),
                 pl.BlockSpec((bm, dk), lambda t, rows, cols: (rows[t], 0)),
-                pl.BlockSpec((dk, bn), lambda t, rows, cols: (0, cols[t])),
+                pl.BlockSpec((bn, dk), lambda t, rows, cols: (cols[t], 0)),
                 pl.BlockSpec((bn, d), lambda t, rows, cols: (cols[t], 0)),
             ],
             out_specs=pl.BlockSpec(
@@ -322,12 +327,12 @@ def fused_attn_sell_kernel(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((mp, d), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="fused_graph_attention_sell",
-    )(tile_rows, tile_cols, mask_blocks, q_perm, kt, v)
+    )(tile_rows, tile_cols, mask_blocks, q_perm, k, v)
 
 
 def fused_attn_sell(sell: SellCS, q, kt, v, *, act: str = "leaky_relu",
@@ -355,13 +360,14 @@ def fused_attn_sell(sell: SellCS, q, kt, v, *, act: str = "leaky_relu",
     n_pad = -(-n // bn) * bn
     q_ext = jnp.concatenate([q, jnp.zeros((1, dk), q.dtype)])
     q_perm = q_ext[sell.perm]  # [n_live*bm, dk]
-    if kt.shape[1] != n_pad:
-        kt = jnp.zeros((dk, n_pad), kt.dtype).at[:, :n].set(kt)
+    k = kt.T
+    if k.shape[0] != n_pad:
+        k = jnp.zeros((n_pad, dk), k.dtype).at[:n].set(k)
     if v.shape[0] != n_pad:
         v = jnp.zeros((n_pad, d), v.dtype).at[:n].set(v)
     mask = (sell_tile_blocks(sell) != 0).astype(jnp.float32)
     y = fused_attn_sell_kernel(
-        sell.tile_rows, sell.tile_cols, mask, q_perm, kt, v,
+        sell.tile_rows, sell.tile_cols, mask, q_perm, k, v,
         n_live_block_rows=sell.n_live_block_rows, act=act, slope=slope,
         out_dtype=out_dtype, interpret=interpret)
     y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])

@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import SellCS
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.fused.epilogue import Epilogue, apply_act, apply_epilogue
 
 
@@ -119,7 +118,7 @@ def spmm_blockell_epilogue_kernel(
             scratch_shapes=[pltpu.VMEM((bm, bd), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nbr * bm, d), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -250,7 +249,7 @@ def spmm_sell_epilogue_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((n_live_block_rows * bm, d),
                                        out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

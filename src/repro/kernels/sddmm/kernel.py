@@ -22,10 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
-
-def _sddmm_kernel(rows_ref, cols_ref, b_ref, c_ref, a_ref, o_ref, acc_ref,
+def _sddmm_kernel(rows_ref, cols_ref, b_ref, ct_ref, a_ref, o_ref, acc_ref,
                   *, n_k: int):
     k = pl.program_id(1)
 
@@ -35,8 +33,8 @@ def _sddmm_kernel(rows_ref, cols_ref, b_ref, c_ref, a_ref, o_ref, acc_ref,
 
     acc_ref[...] += jax.lax.dot_general(
         b_ref[...],
-        c_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        ct_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -54,7 +52,7 @@ def sddmm_blockcoo_kernel(
     cols,  # int32[nnzb]
     mask_blocks,  # dtype[nnzb, bm, bn]
     b,  # dtype[M, K]
-    c,  # dtype[K, N]
+    c_t,  # dtype[N, K]  (C transposed: N-major)
     *,
     bk: int = 128,
     out_dtype=jnp.float32,
@@ -62,7 +60,7 @@ def sddmm_blockcoo_kernel(
 ):
     nnzb, bm, bn = mask_blocks.shape
     m, k = b.shape
-    k2, n = c.shape
+    n, k2 = c_t.shape
     assert k == k2 and k % bk == 0, (k, bk)
 
     grid = (nnzb, k // bk)
@@ -74,7 +72,7 @@ def sddmm_blockcoo_kernel(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda e, kk, rows, cols: (rows[e], kk)),
-                pl.BlockSpec((bk, bn), lambda e, kk, rows, cols: (kk, cols[e])),
+                pl.BlockSpec((bn, bk), lambda e, kk, rows, cols: (cols[e], kk)),
                 pl.BlockSpec((1, bm, bn), lambda e, kk, rows, cols: (e, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
@@ -83,10 +81,10 @@ def sddmm_blockcoo_kernel(
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nnzb, bm, bn), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="sddmm_blockcoo",
-    )(rows, cols, b, c, mask_blocks)
+    )(rows, cols, b, c_t, mask_blocks)
     return out

@@ -35,7 +35,7 @@ def sddmm_blockcoo(
     if k % bk != 0:
         raise ValueError(f"K={k} not divisible by bk={bk}")
     out_blocks = sddmm_blockcoo_kernel(
-        coo.rows, coo.cols, coo.blocks, b, c,
+        coo.rows, coo.cols, coo.blocks, b, c.T,
         bk=bk, out_dtype=out_dtype, interpret=interpret,
     )
     return BlockCOO(

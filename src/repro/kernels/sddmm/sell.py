@@ -2,10 +2,11 @@
 
 Samples B @ C only at the live tiles of a SELL-packed operand: the grid
 walks the flat live-tile descriptor (scalar-prefetched), streams the
-(bm x bk) B tile and (bk x bn) C tile each live tile needs, contracts
+(bm x bk) B tile and (bn x bk) Cᵀ tile each live tile needs, contracts
 over K with the accumulator resident in VMEM, and masks with the tile's
 structural pattern at the flush — all-zero row slices were pruned at
-pack time, so no grid step ever samples a dead tile.
+pack time, so no grid step ever samples a dead tile.  C arrives N-major
+(``c_t``), for the same tiling reason as the Block-COO kernel.
 
 Because SELL packs *permuted* rows, the caller passes B already gathered
 into packed row order (``b[perm]`` — the row gather the descriptor
@@ -14,7 +15,7 @@ slot (element) order.
 
 Grid: (T, K/bk)   [K innermost => sequential accumulation]
   B_perm: [L*bm, K]     -> tile (bm, bk)    at (rows[t], k)
-  C:      [K, Np]       -> tile (bk, bn)    at (k, cols[t])
+  Cᵀ:     [Np, K]       -> tile (bn, bk)    at (cols[t], k)
   mask:   [T, bm, bn]   -> tile (1, bm, bn) at (t, 0, 0)
   Y:      [T, bm, bn]   -> tile (1, bm, bn) at (t, 0, 0), revisited in k
 """
@@ -28,10 +29,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import SellCS
-from repro.kernels._compat import tpu_compiler_params
 
 
-def _sell_sddmm_kernel(rows_ref, cols_ref, b_ref, c_ref, mask_ref, o_ref,
+def _sell_sddmm_kernel(rows_ref, cols_ref, b_ref, ct_ref, mask_ref, o_ref,
                        acc_ref, *, n_k: int):
     k = pl.program_id(1)
 
@@ -41,8 +41,8 @@ def _sell_sddmm_kernel(rows_ref, cols_ref, b_ref, c_ref, mask_ref, o_ref,
 
     acc_ref[...] += jax.lax.dot_general(
         b_ref[...],
-        c_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        ct_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -60,7 +60,7 @@ def sddmm_sell_kernel(
     tile_cols,  # int32[T] block-column per tile
     mask_blocks,  # dtype[T, bm, bn] structural 0/1 pattern of each tile
     b_perm,  # dtype[L*bm, K]  B gathered into packed row order
-    c,  # dtype[K, Np]
+    c_t,  # dtype[Np, K]  (C transposed: N-major)
     *,
     bk: int = 128,
     out_dtype=jnp.float32,
@@ -68,7 +68,7 @@ def sddmm_sell_kernel(
 ):
     t_count, bm, bn = mask_blocks.shape
     m, k = b_perm.shape
-    k2, n = c.shape
+    n, k2 = c_t.shape
     assert k == k2 and k % bk == 0, (k, bk)
 
     grid = (t_count, k // bk)
@@ -83,7 +83,7 @@ def sddmm_sell_kernel(
                     (bm, bk), lambda t, kk, rows, cols: (rows[t], kk)
                 ),
                 pl.BlockSpec(
-                    (bk, bn), lambda t, kk, rows, cols: (kk, cols[t])
+                    (bn, bk), lambda t, kk, rows, cols: (cols[t], kk)
                 ),
                 pl.BlockSpec(
                     (1, bm, bn), lambda t, kk, rows, cols: (t, 0, 0)
@@ -95,29 +95,13 @@ def sddmm_sell_kernel(
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((t_count, bm, bn), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="sddmm_sell",
-    )(tile_rows, tile_cols, b_perm, c, mask_blocks)
+    )(tile_rows, tile_cols, b_perm, c_t, mask_blocks)
     return out
-
-
-def sddmm_sell_tiles_ref(tile_rows, tile_cols, mask_blocks, b_perm, c,
-                         *, out_dtype=jnp.float32):
-    """Pure-jnp oracle of the kernel's masked tile output."""
-    t_count, bm, bn = mask_blocks.shape
-    m, k = b_perm.shape
-    _, n = c.shape
-    b_tiles = b_perm.reshape(m // bm, bm, k)[tile_rows]
-    c_tiles = c.reshape(k, n // bn, bn).transpose(1, 0, 2)[tile_cols]
-    prod = jnp.einsum(
-        "tmk,tkn->tmn",
-        b_tiles.astype(jnp.float32),
-        c_tiles.astype(jnp.float32),
-    )
-    return (mask_blocks.astype(jnp.float32) * prod).astype(out_dtype)
 
 
 def sample_sell_blocked(sell: SellCS, b, c, *, bk: int | None = None,
@@ -138,11 +122,12 @@ def sample_sell_blocked(sell: SellCS, b, c, *, bk: int | None = None,
     n_pad = -(-n // bn) * bn
     b_ext = jnp.concatenate([b, jnp.zeros((1, k), b.dtype)])
     b_perm = b_ext[sell.perm]  # [n_live*bm, K]; padding rows are zero
-    if c.shape[1] != n_pad:
-        c = jnp.zeros((k, n_pad), c.dtype).at[:, :n].set(c)
+    c_t = c.T
+    if c_t.shape[0] != n_pad:
+        c_t = jnp.zeros((n_pad, k), c_t.dtype).at[:n].set(c_t)
     mask = (sell.tile_slot_map < n_slots).astype(b.dtype)
     tiles = sddmm_sell_kernel(
-        sell.tile_rows, sell.tile_cols, mask, b_perm, c,
+        sell.tile_rows, sell.tile_cols, mask, b_perm, c_t,
         bk=bk or _pick_bk(k), out_dtype=jnp.float32, interpret=interpret)
     flat = jnp.concatenate([tiles.reshape(-1), jnp.zeros((1,), tiles.dtype)])
     return flat[sell.slot_tile_pos]

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _spmm_kernel(idx_ref, a_ref, h_ref, o_ref, acc_ref, *, n_slots: int):
     """One grid step: o[i, j] += A[i, k] @ H[idx[i, k], j]."""
@@ -93,7 +91,7 @@ def spmm_blockell_kernel(
             scratch_shapes=[pltpu.VMEM((bm, bd), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nbr * bm, d), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

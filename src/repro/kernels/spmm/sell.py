@@ -32,7 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import SellCS
-from repro.kernels._compat import tpu_compiler_params
 
 
 def _sell_spmm_kernel(rows_ref, cols_ref, a_ref, h_ref, o_ref, acc_ref,
@@ -102,7 +101,7 @@ def spmm_sell_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((n_live_block_rows * bm, d),
                                        out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -156,13 +155,13 @@ def moe_ffn(p, x, cfg: ModelConfig):
             aux = jax.lax.pmean(aux, mesh.axis_names)
             return yl.reshape(x_local.shape[0], s, d), aux
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(P(), P(bspec, None, None))
             + tuple(P("model") for _ in ws),
             out_specs=(P(bspec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         y3, aux = fn(p["router"], x, *ws)
         y = y3.reshape(-1, d)

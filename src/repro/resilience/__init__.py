@@ -26,7 +26,7 @@ from repro.resilience import chaos
 from repro.resilience.chaos import (FaultPlan, FaultSpec,
                                     ProcessKillRequested,
                                     WorkerHangRequested, WorkerKilled)
-from repro.resilience.errors import (DeadlineExceededError,
+from repro.resilience.errors import (CompileError, DeadlineExceededError,
                                      EngineClosedError, NaNOutputError,
                                      PoisonRequestError, RequestShedError,
                                      ResilienceError,
@@ -36,7 +36,8 @@ from repro.resilience.retry import RetryBudget, RetryPolicy, call_with_retry
 from repro.resilience.supervisor import WorkerSupervisor
 
 __all__ = [
-    "DeadlineExceededError", "EngineClosedError", "FaultPlan", "FaultSpec",
+    "CompileError", "DeadlineExceededError", "EngineClosedError",
+    "FaultPlan", "FaultSpec",
     "NaNOutputError", "PoisonRequestError", "ProcessKillRequested",
     "RequestShedError", "ResilienceError", "RetryBudget", "RetryPolicy",
     "TransientExecutorError", "WorkerHangRequested", "WorkerKilled",

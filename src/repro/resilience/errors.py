@@ -18,6 +18,9 @@ therefore what to do about it:
   plain ``except TimeoutError`` works.
 * :class:`EngineClosedError` — the engine shut down; subclasses
   :class:`RuntimeError` for compatibility with pre-taxonomy callers.
+* :class:`CompileError` — a traced program failed to lower or compile
+  (e.g. the TPU compiler refused a kernel).  No request is to blame and
+  no retry or other form can paper over it: it fails the batch as is.
 
 ``classify()`` maps an arbitrary exception onto the retry decision.
 """
@@ -64,6 +67,14 @@ class EngineClosedError(ResilienceError):
     """The engine was closed; the request cannot be (or was not) run."""
 
 
+class CompileError(ResilienceError):
+    """The executor's program traced but did not lower or compile.
+
+    A kernel the device's compiler refuses is a defect of the program,
+    not of the request or the infrastructure, so it is never retried,
+    quarantined or degraded onto another form."""
+
+
 #: classification tags returned by :func:`classify`
 POISON = "poison"
 TRANSIENT = "transient"
@@ -81,7 +92,7 @@ def classify(exc: BaseException) -> str:
     if isinstance(exc, PoisonRequestError):
         return POISON
     if isinstance(exc, (EngineClosedError, DeadlineExceededError,
-                        RequestShedError)):
+                        RequestShedError, CompileError)):
         return FATAL
     if isinstance(exc, (KeyboardInterrupt, SystemExit)):
         return FATAL
@@ -94,7 +105,7 @@ def classify(exc: BaseException) -> str:
 
 
 __all__ = [
-    "DeadlineExceededError", "EngineClosedError", "FATAL", "NaNOutputError",
+    "CompileError", "DeadlineExceededError", "EngineClosedError", "FATAL", "NaNOutputError",
     "POISON", "PoisonRequestError", "RequestShedError", "ResilienceError",
     "TRANSIENT", "TransientExecutorError", "WorkerLostError", "classify",
 ]

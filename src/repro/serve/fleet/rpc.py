@@ -192,13 +192,25 @@ class ThreadHandle:
 
 
 class ProcessHandle:
-    """Real ``spawn`` child process; ``kill()`` is SIGKILL."""
+    """Real ``spawn`` child process; ``kill()`` is SIGKILL.
+
+    CPU backend only: a TPU belongs to one process at a time, and the
+    parent that imported JAX already holds it, so a child would fail or
+    hang reaching the chip.  On TPU serve with the thread backend.
+    """
 
     backend = "process"
 
     def __init__(self, name: str, worker_cfg) -> None:
         import dataclasses
 
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"fleet worker {name!r}: the process backend cannot run on "
+                "TPU — the parent process holds the chip and a spawned "
+                "worker cannot reach it; use backend='thread'")
         from repro.serve.fleet.worker import _process_main
         self.name = name
         ctx = mp.get_context("spawn")

@@ -22,30 +22,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 
 
-# ---------------------------------------------------------------------------
-# Version-guarded mesh construction
-# ---------------------------------------------------------------------------
-#
-# ``jax.sharding.AxisType`` only exists from jax 0.5 onward; the pinned
-# jax 0.4.37 builds meshes without explicit axis types (every axis is
-# "auto" there anyway).  All mesh construction in the repo goes through
-# ``make_mesh`` so the guard lives in exactly one place.
-
-
-def axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh`` if this jax supports it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axes, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with auto axis types on jax versions that have them."""
-    kw = axis_types_kw(len(axes))
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    """``jax.make_mesh`` with every axis in auto (compiler-chosen) mode."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 # key -> (logical spec per trailing dims of the UNSTACKED param)

@@ -30,9 +30,9 @@ import dataclasses
 from repro.dispatch import autotune as autotune_mod
 from repro.dispatch.autotune import AutotuneCache, make_key, measure
 from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
-from repro.dispatch.dispatcher import (Plan, plan_fused_attention,
-                                       plan_sddmm, plan_spmm, plan_spmv,
-                                       record_plan)
+from repro.dispatch.dispatcher import (Plan, default_use_kernel,
+                                       plan_fused_attention, plan_sddmm,
+                                       plan_spmm, plan_spmv, record_plan)
 from repro.dispatch.policy import (DEFAULT_CONFIG, DispatchConfig, PATHS,
                                    PATH_CSR, PATH_DENSE, PATH_ELL,
                                    PATH_FUSED_ATTN, PATH_SELL, POLICY_AUTO,
@@ -40,12 +40,6 @@ from repro.dispatch.policy import (DEFAULT_CONFIG, DispatchConfig, PATHS,
 from repro.kernels.fused.epilogue import normalize_epilogue
 from repro.sparse import autodiff
 from repro.sparse.matrix import SparseMatrix, with_values
-
-
-def _default_use_kernel(config: DispatchConfig) -> bool:
-    if config.use_kernel is not None:
-        return config.use_kernel
-    return jax.default_backend() == "tpu"
 
 
 def _is_traced(*operands) -> bool:
@@ -232,7 +226,7 @@ def matmul(
     epi = normalize_epilogue(epilogue, bias, residual)
     policy = normalize_policy(policy)
     cand = tuple(candidates) if candidates else available_paths(a)
-    uk = use_kernel if use_kernel is not None else _default_use_kernel(config)
+    uk = use_kernel if use_kernel is not None else default_use_kernel(config)
     interpret = bool(interpret)
     odt = None if out_dtype is None else str(jnp.dtype(out_dtype))
 
@@ -299,7 +293,7 @@ def spmv(
             f"columns (A shape {a.shape})")
     policy = normalize_policy(policy)
     cand = tuple(candidates) if candidates else available_paths(a)
-    uk = use_kernel if use_kernel is not None else _default_use_kernel(config)
+    uk = use_kernel if use_kernel is not None else default_use_kernel(config)
     interpret = bool(interpret)
     odt = None if out_dtype is None else str(jnp.dtype(out_dtype))
 
@@ -357,7 +351,7 @@ def sddmm(
             f"sddmm: inner dims disagree: B {b.shape} vs C {c.shape}")
     policy = normalize_policy(policy)
     cand = tuple(candidates) if candidates else available_paths(a)
-    uk = use_kernel if use_kernel is not None else _default_use_kernel(config)
+    uk = use_kernel if use_kernel is not None else default_use_kernel(config)
     interpret = bool(interpret)
     odt = None if out_dtype is None else str(jnp.dtype(out_dtype))
 
@@ -449,7 +443,7 @@ def fused_graph_attention(
             f"vs k {k.shape}")
     policy = normalize_policy(policy)
     cand = tuple(candidates) if candidates else available_paths(a)
-    uk = use_kernel if use_kernel is not None else _default_use_kernel(config)
+    uk = use_kernel if use_kernel is not None else default_use_kernel(config)
     interpret = bool(interpret)
     slope = float(negative_slope)
     odt = None if out_dtype is None else str(jnp.dtype(out_dtype))

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,7 +82,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh: Mesh,
         jax.tree_util.tree_map(lambda _: P(axis), stage_params),
         P(),
     )
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                   check_vma=False)
     del other
     return fn(stage_params, x_micro)

@@ -475,3 +475,19 @@ def test_gnn_serving_engine_dispatch_report():
     eng2 = GNNServingEngine(params, g, GNNServeConfig(policy=other))
     np.testing.assert_allclose(eng2.infer(x), logits, rtol=2e-4, atol=2e-4)
     assert eng2.dispatch_report()["path"] == other
+
+
+def test_autotune_measure_surfaces_a_raising_candidate():
+    """A path that raises (a kernel the compiler refuses) is a fault to
+    surface, not an `inf` timing that quietly loses the race."""
+    from repro.dispatch.autotune import measure
+
+    def refused():
+        raise ValueError("block shape refused by the compiler")
+
+    with pytest.raises(ValueError, match="refused"):
+        measure({"ell": refused, "csr": lambda: jnp.ones(4)},
+                warmup=0, iters=1)
+    m = measure({"ell": lambda: jnp.ones(4), "csr": lambda: jnp.ones(4)},
+                warmup=0, iters=1)
+    assert m.path in ("ell", "csr") and set(m.timings_us) == {"ell", "csr"}

@@ -96,3 +96,46 @@ def test_distributed_spmm_and_sharded_train():
     for tag in ("1.5D OK", "2D OK", "2.5D OK", "collective-matmul OK",
                 "sharded-train-parity OK"):
         assert tag in out.stdout
+
+
+def _one_device_ell_problem():
+    import numpy as np
+
+    from repro.core.formats import BlockELL
+
+    rng = np.random.default_rng(0)
+    dense = (rng.normal(size=(64, 64)) * (rng.random((64, 64)) < 0.2)) \
+        .astype(np.float32)
+    h = rng.normal(size=(64, 16)).astype(np.float32)
+    return dense, BlockELL.from_dense(dense, bm=16, bn=16), h
+
+
+@pytest.mark.parametrize("backend,expect_kernel", [("tpu", True),
+                                                   ("cpu", False)])
+def test_spmm_1p5d_plans_the_kernel_from_the_backend(monkeypatch, backend,
+                                                     expect_kernel):
+    """Without a use_kernel argument the 1.5D path follows the
+    dispatcher's backend rule: the Pallas kernel on TPU, the reference
+    elsewhere (never a hard-coded reference default)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import distributed
+    from repro.sharding.specs import make_mesh
+
+    seen = []
+    real = distributed.spmm_blockell
+
+    def recording(ell, h, *, use_kernel):
+        seen.append(use_kernel)
+        return real(ell, h, use_kernel=False)  # the CPU runs the reference
+
+    monkeypatch.setattr(distributed, "spmm_blockell", recording)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    dense, ell, h = _one_device_ell_problem()
+    mesh = make_mesh((1,), ("data",))
+    y = distributed.spmm_1p5d(ell, jnp.asarray(h), mesh)
+    assert seen == [expect_kernel]
+    np.testing.assert_allclose(np.asarray(y), dense @ h, rtol=1e-5,
+                               atol=1e-5)

@@ -555,3 +555,16 @@ class TestProcessBackend:
         finally:
             chaos.uninstall()
             fleet.close()
+
+
+def test_process_backend_refuses_to_start_on_tpu(monkeypatch):
+    """Only one process may hold a TPU, and the parent already does: the
+    process backend refuses with a clear error instead of spawning a
+    worker that would fail or hang reaching the chip."""
+    import jax
+
+    from repro.serve.fleet.rpc import ProcessHandle
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="backend='thread'"):
+        ProcessHandle("w0", object())

@@ -28,7 +28,8 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.resilience import (DeadlineExceededError, FaultPlan, FaultSpec,
+from repro.resilience import (CompileError, DeadlineExceededError,
+                              FaultPlan, FaultSpec,
                               PoisonRequestError, RequestShedError,
                               RetryPolicy, TransientExecutorError, chaos)
 from repro.serve.runtime import ContinuousBatchEngine, ContinuousConfig
@@ -100,6 +101,43 @@ def test_poison_bisection_quarantines_only_culprit(rng):
     snap = obs.snapshot()
     assert _counter_total(snap, "resilience_quarantined_total") == 1
     assert _counter_total(snap, "chaos_faults_total") >= 1
+
+
+def _refused_by_the_compiler(mat, h):
+    """Traces, then fails to lower: a Pallas kernel without interpret
+    mode has no CPU lowering — the CPU stand-in for a kernel the TPU
+    compiler refuses."""
+    from jax.experimental import pallas as pl
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    return pl.pallas_call(copy, out_shape=jax.ShapeDtypeStruct(
+        h.shape, h.dtype))(h)
+
+
+def test_compile_failure_surfaces_not_quarantined_or_degraded(rng):
+    """A program that traces but does not compile fails its requests
+    with CompileError: no request is quarantined as poison, no retry
+    runs, and the form is not degraded onto another path."""
+    with ContinuousBatchEngine(_refused_by_the_compiler,
+                               cfg=_cfg()) as eng:
+        futs = []
+        for _ in range(3):
+            _, mat = _graph(rng, 48)
+            h = jnp.asarray(rng.normal(size=(48, D)).astype(np.float32))
+            futs.append(eng.submit(mat, h))
+        eng.drain()
+        for f in futs:
+            with pytest.raises(CompileError, match="failed to compile"):
+                f.result()
+        rep = eng.report()
+        assert rep["resilience"]["quarantined"] == 0
+        assert "degraded" not in rep["executor"]
+    snap = obs.snapshot()
+    assert _counter_total(snap, "resilience_quarantined_total") == 0
+    assert _counter_total(snap, "resilience_degraded_total") == 0
+    assert _counter_total(snap, "resilience_retries_total") == 0
 
 
 def test_transient_fault_retries_and_succeeds(rng):
