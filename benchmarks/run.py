@@ -70,7 +70,7 @@ def main() -> None:
                             bench_dense_limit, bench_footprint, bench_fused,
                             bench_sddmm, bench_serve, bench_serve_fleet,
                             bench_spmm, common)
-    from repro.sparse import plan_cache_stats, reset_plan_cache_stats
+    from repro.sparse import plan_cache_stats
     benches = {
         "dense_limit": bench_dense_limit.run,
         "footprint": bench_footprint.run,
@@ -90,7 +90,6 @@ def main() -> None:
         if unknown:
             ap.error(f"unknown bench name(s) {sorted(unknown)}; "
                      f"expected among {sorted(benches)}")
-    reset_plan_cache_stats()
     common.reset_rows()
     print("name,us_per_call,derived")
 

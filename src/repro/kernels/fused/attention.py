@@ -101,6 +101,7 @@ def _ell_attn_kernel(idx_ref, a_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(
     jax.jit, static_argnames=("act", "slope", "out_dtype", "interpret")
 )
+@jax.named_scope("sparse.kernel.fused_graph_attention_blockell")
 def fused_attn_blockell_kernel(
     indices,  # int32[nbr, W]
     blocks,  # dtype[nbr, W, bm, bn]  structural mask source
@@ -150,6 +151,7 @@ def fused_attn_blockell_kernel(
     )(indices, blocks, q, k, v)
 
 
+@jax.named_scope("sparse.xla.fused_attn_blockell_ref")
 def fused_attn_blockell_ref(ell: BlockELL, q, kt, v, *,
                             act: str = "leaky_relu", slope: float = 0.2,
                             out_dtype=jnp.float32):
@@ -185,16 +187,11 @@ def fused_attn_blockell(ell: BlockELL, q, kt, v, *,
     shapes; padding to the block grid happens here, the caller trims the
     output to the logical row count.
     """
+    from repro.sparse.paths import pad_cols, pad_rows
+
     out_dtype = out_dtype or jnp.result_type(q.dtype, v.dtype)
     mp, np_ = ell.shape
-    dk = q.shape[1]
-    d = v.shape[1]
-    if q.shape[0] != mp:
-        q = jnp.zeros((mp, dk), q.dtype).at[: q.shape[0]].set(q)
-    if kt.shape[1] != np_:
-        kt = jnp.zeros((dk, np_), kt.dtype).at[:, : kt.shape[1]].set(kt)
-    if v.shape[0] != np_:
-        v = jnp.zeros((np_, d), v.dtype).at[: v.shape[0]].set(v)
+    q, kt, v = pad_rows(q, mp), pad_cols(kt, np_), pad_rows(v, np_)
     if use_kernel or interpret:
         return fused_attn_blockell_kernel(
             ell.indices, ell.blocks, q, kt.T, v, act=act, slope=slope,
@@ -203,6 +200,7 @@ def fused_attn_blockell(ell: BlockELL, q, kt, v, *,
                                    out_dtype=out_dtype)
 
 
+@jax.named_scope("sparse.xla.fused_attn_blockcoo_ref")
 def fused_attn_blockcoo_ref(coo, q, kt, v, *, act: str = "leaky_relu",
                             slope: float = 0.2, out_dtype=jnp.float32):
     """Blocked two-sweep over Block-COO (the transposed-ELL layout).
@@ -283,6 +281,7 @@ def _sell_attn_kernel(rows_ref, cols_ref, mask_ref, q_ref, k_ref, v_ref,
     static_argnames=("n_live_block_rows", "act", "slope", "out_dtype",
                      "interpret"),
 )
+@jax.named_scope("sparse.kernel.fused_graph_attention_sell")
 def fused_attn_sell_kernel(
     tile_rows,  # int32[T]
     tile_cols,  # int32[T]
@@ -346,7 +345,6 @@ def fused_attn_sell(sell: SellCS, q, kt, v, *, act: str = "leaky_relu",
     """
     out_dtype = out_dtype or jnp.result_type(q.dtype, v.dtype)
     m, n = sell.shape
-    dk = q.shape[1]
     d = v.shape[1]
     if not (use_kernel or interpret):
         return fused_attn_sell_slots_ref(sell, q, kt, v, act=act,
@@ -354,24 +352,21 @@ def fused_attn_sell(sell: SellCS, q, kt, v, *, act: str = "leaky_relu",
     if sell.n_tiles == 0:
         return jnp.zeros((m, d), out_dtype)
 
-    from repro.kernels.spmm.sell import sell_tile_blocks
+    from repro.kernels.spmm.sell import (permute_rows, sell_tile_blocks,
+                                         unpermute_rows)
+    from repro.sparse.paths import pad_rows
 
-    bn = sell.bn
-    n_pad = -(-n // bn) * bn
-    q_ext = jnp.concatenate([q, jnp.zeros((1, dk), q.dtype)])
-    q_perm = q_ext[sell.perm]  # [n_live*bm, dk]
-    k = kt.T
-    if k.shape[0] != n_pad:
-        k = jnp.zeros((n_pad, dk), k.dtype).at[:n].set(k)
-    if v.shape[0] != n_pad:
-        v = jnp.zeros((n_pad, d), v.dtype).at[:n].set(v)
-    mask = (sell_tile_blocks(sell) != 0).astype(jnp.float32)
+    n_pad = -(-n // sell.bn) * sell.bn
+    q_perm = permute_rows(sell, q)  # [n_live*bm, dk]
+    k = pad_rows(kt.T, n_pad)
+    v = pad_rows(v, n_pad)
+    with jax.named_scope("sparse.layout.tile_values"):
+        mask = (sell_tile_blocks(sell) != 0).astype(jnp.float32)
     y = fused_attn_sell_kernel(
         sell.tile_rows, sell.tile_cols, mask, q_perm, k, v,
         n_live_block_rows=sell.n_live_block_rows, act=act, slope=slope,
         out_dtype=out_dtype, interpret=interpret)
-    y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
-    return y_ext[sell.tile_out_gather]
+    return unpermute_rows(sell, y)
 
 
 def fused_attn_sell_slots_ref(sell: SellCS, q, kt, v, *,
@@ -393,6 +388,7 @@ def fused_attn_sell_slots_ref(sell: SellCS, q, kt, v, *,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sparse.xla.fused_attn_elements")
 def fused_attn_elements(row_ids, col_ids, values, q, kt, v, m: int, *,
                         act: str = "leaky_relu", slope: float = 0.2,
                         out_dtype=None):
@@ -412,6 +408,7 @@ def fused_attn_elements(row_ids, col_ids, values, q, kt, v, m: int, *,
     return y.astype(out_dtype)
 
 
+@jax.named_scope("sparse.xla.fused_attn_dense")
 def fused_attn_dense(a_dense, q, kt, v, *, act: str = "leaky_relu",
                      slope: float = 0.2, out_dtype=None):
     """Densified fallback: masked row softmax over the full product."""

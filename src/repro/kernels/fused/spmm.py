@@ -78,6 +78,7 @@ def _ell_fused_kernel(idx_ref, a_ref, h_ref, *rest, n_slots: int,
     jax.jit,
     static_argnames=("epi", "bd", "out_dtype", "interpret"),
 )
+@jax.named_scope("sparse.kernel.spmm_blockell_epilogue")
 def spmm_blockell_epilogue_kernel(
     indices,  # int32[nbr, W]
     blocks,  # dtype[nbr, W, bm, bn]
@@ -137,26 +138,20 @@ def spmm_blockell_fused(ell, h, epi: Epilogue, bias=None, residual=None,
     carries *logical* rows and is zero-padded here.
     """
     from repro.kernels.spmm.ops import _pick_bd, spmm_blockell
+    from repro.sparse.paths import pad_rows
 
     out_dtype = out_dtype or jnp.result_type(ell.blocks.dtype, h.dtype)
     if not (use_kernel or interpret):
         y = spmm_blockell(ell, h, bd=bd, out_dtype=out_dtype,
                           use_kernel=False)
-        res = residual
-        if res is not None and res.shape[0] != y.shape[0]:
-            res = jnp.zeros((y.shape[0],) + res.shape[1:], res.dtype) \
-                .at[: res.shape[0]].set(res)
+        res = None if residual is None else pad_rows(residual, y.shape[0])
         return apply_epilogue(y, epi, bias, res)
     d = h.shape[1]
     mp = ell.n_block_rows * ell.bm
     bias2d = None
     if epi.has_bias:
         bias2d = jnp.asarray(bias).reshape(1, d)
-    res = None
-    if epi.has_residual:
-        res = residual
-        if res.shape[0] != mp:
-            res = jnp.zeros((mp, d), res.dtype).at[: res.shape[0]].set(res)
+    res = pad_rows(residual, mp) if epi.has_residual else None
     return spmm_blockell_epilogue_kernel(
         ell.indices, ell.blocks, h, bias2d, res,
         epi=epi, bd=bd or _pick_bd(d), out_dtype=out_dtype,
@@ -203,6 +198,7 @@ def _sell_fused_kernel(rows_ref, cols_ref, a_ref, h_ref, *rest,
     static_argnames=("epi", "n_live_block_rows", "bd", "out_dtype",
                      "interpret"),
 )
+@jax.named_scope("sparse.kernel.spmm_sell_epilogue")
 def spmm_sell_epilogue_kernel(
     tile_rows,  # int32[T]
     tile_cols,  # int32[T]
@@ -281,25 +277,20 @@ def spmm_sell_fused(sell: SellCS, h, epi: Epilogue, bias=None,
         y = jnp.zeros((m, d), out_dtype)
         return apply_epilogue(y, epi, bias, residual)
 
-    from repro.kernels.spmm.sell import sell_tile_blocks
+    from repro.kernels.spmm.sell import (permute_rows, sell_tile_blocks,
+                                         unpermute_rows)
+    from repro.sparse.paths import pad_rows
 
     bn = sell.bn
-    n_pad = -(-n // bn) * bn
-    if h.shape[0] != n_pad:
-        h = jnp.zeros((n_pad, d), h.dtype).at[:n].set(h)
+    h = pad_rows(h, -(-n // bn) * bn)
     bias2d = jnp.asarray(bias).reshape(1, d) if epi.has_bias else None
-    res_perm = None
-    if epi.has_residual:
-        res_ext = jnp.concatenate(
-            [residual, jnp.zeros((1, d), residual.dtype)])
-        res_perm = res_ext[sell.perm]  # packed row order; pad rows zero
+    res_perm = permute_rows(sell, residual) if epi.has_residual else None
     y = spmm_sell_epilogue_kernel(
         sell.tile_rows, sell.tile_cols, sell_tile_blocks(sell), h,
         bias2d, res_perm, epi=epi,
         n_live_block_rows=sell.n_live_block_rows,
         bd=bd or _pick_bd(d), out_dtype=out_dtype, interpret=interpret)
-    y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
-    out = y_ext[sell.tile_out_gather]
+    out = unpermute_rows(sell, y)
     if epi.has_bias or epi.has_residual:
         # pruned rows (A row all-zero): out = act(bias + residual[row])
         zero = jnp.zeros((m, d), jnp.float32)

@@ -47,6 +47,7 @@ def _sddmm_kernel(rows_ref, cols_ref, b_ref, ct_ref, a_ref, o_ref, acc_ref,
 @functools.partial(
     jax.jit, static_argnames=("bk", "out_dtype", "interpret")
 )
+@jax.named_scope("sparse.kernel.sddmm_blockcoo")
 def sddmm_blockcoo_kernel(
     rows,  # int32[nnzb]
     cols,  # int32[nnzb]

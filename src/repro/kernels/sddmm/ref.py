@@ -1,11 +1,13 @@
 """Pure-jnp oracle for Block-COO SDDMM: Y = A ⊙ (B @ C)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.formats import BlockCOO
 
 
+@jax.named_scope("sparse.xla.sddmm_blockcoo_ref")
 def sddmm_blockcoo_ref(coo: BlockCOO, b, c, *, out_dtype=None):
     """Reference SDDMM.
 

@@ -55,6 +55,7 @@ def _sell_sddmm_kernel(rows_ref, cols_ref, b_ref, ct_ref, mask_ref, o_ref,
 @functools.partial(
     jax.jit, static_argnames=("bk", "out_dtype", "interpret")
 )
+@jax.named_scope("sparse.kernel.sddmm_sell")
 def sddmm_sell_kernel(
     tile_rows,  # int32[T] compact live block-row per tile
     tile_cols,  # int32[T] block-column per tile
@@ -112,22 +113,22 @@ def sample_sell_blocked(sell: SellCS, b, c, *, bk: int | None = None,
     float32[n_slots] — padding slots read the appended zero cell.
     """
     from repro.kernels.sddmm.ops import _pick_bk
+    from repro.kernels.spmm.sell import permute_rows
+    from repro.sparse.paths import pad_rows
 
     m, n = sell.shape
     k = b.shape[1]
     n_slots = sell.n_slots
     if sell.n_tiles == 0:
         return jnp.zeros((n_slots,), jnp.float32)
-    bn = sell.bn
-    n_pad = -(-n // bn) * bn
-    b_ext = jnp.concatenate([b, jnp.zeros((1, k), b.dtype)])
-    b_perm = b_ext[sell.perm]  # [n_live*bm, K]; padding rows are zero
-    c_t = c.T
-    if c_t.shape[0] != n_pad:
-        c_t = jnp.zeros((n_pad, k), c_t.dtype).at[:n].set(c_t)
-    mask = (sell.tile_slot_map < n_slots).astype(b.dtype)
+    b_perm = permute_rows(sell, b)  # [n_live*bm, K]; padding rows zero
+    c_t = pad_rows(c.T, -(-n // sell.bn) * sell.bn)
+    with jax.named_scope("sparse.layout.tile_mask"):
+        mask = (sell.tile_slot_map < n_slots).astype(b.dtype)
     tiles = sddmm_sell_kernel(
         sell.tile_rows, sell.tile_cols, mask, b_perm, c_t,
         bk=bk or _pick_bk(k), out_dtype=jnp.float32, interpret=interpret)
-    flat = jnp.concatenate([tiles.reshape(-1), jnp.zeros((1,), tiles.dtype)])
-    return flat[sell.slot_tile_pos]
+    with jax.named_scope("sparse.layout.tile_slots"):
+        flat = jnp.concatenate([tiles.reshape(-1),
+                                jnp.zeros((1,), tiles.dtype)])
+        return flat[sell.slot_tile_pos]

@@ -59,6 +59,7 @@ def _spmm_kernel(idx_ref, a_ref, h_ref, o_ref, acc_ref, *, n_slots: int):
     jax.jit,
     static_argnames=("bd", "out_dtype", "interpret"),
 )
+@jax.named_scope("sparse.kernel.spmm_blockell")
 def spmm_blockell_kernel(
     indices,  # int32[nbr, W]
     blocks,  # dtype[nbr, W, bm, bn]

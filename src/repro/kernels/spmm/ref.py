@@ -1,11 +1,13 @@
 """Pure-jnp oracle for Block-ELL SpMM: Y = A @ H."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.formats import BlockELL
 
 
+@jax.named_scope("sparse.xla.spmm_blockell_ref")
 def spmm_blockell_ref(ell: BlockELL, h, *, out_dtype=None):
     """Reference Y = A @ H with A in Block-ELL.
 

@@ -62,6 +62,7 @@ def _sell_spmm_kernel(rows_ref, cols_ref, a_ref, h_ref, o_ref, acc_ref,
     jax.jit,
     static_argnames=("n_live_block_rows", "bd", "out_dtype", "interpret"),
 )
+@jax.named_scope("sparse.kernel.spmm_sell")
 def spmm_sell_kernel(
     tile_rows,  # int32[T]  compact live block-row per tile (ascending)
     tile_cols,  # int32[T]  block-column per tile
@@ -126,6 +127,7 @@ def spmm_sell_tiles_ref(tile_rows, tile_cols, tile_blocks, h,
     return out.reshape(n_live_block_rows * bm, d).astype(out_dtype)
 
 
+@jax.named_scope("sparse.layout.tile_values")
 def sell_tile_blocks(sell: SellCS):
     """Gather the live-tile data from the slot values (trace-safe).
 
@@ -146,6 +148,7 @@ def spmm_sell_blocked(sell: SellCS, h, *, bd: int | None = None,
     pruned all-zero rows, and trims to the logical row count.
     """
     from repro.kernels.spmm.ops import _pick_bd
+    from repro.sparse.paths import pad_rows
 
     out_dtype = out_dtype or jnp.result_type(sell.slot_vals.dtype, h.dtype)
     m, n = sell.shape
@@ -154,11 +157,26 @@ def spmm_sell_blocked(sell: SellCS, h, *, bd: int | None = None,
         return jnp.zeros((m, d), out_dtype)
     bn = sell.bn
     n_pad = -(-n // bn) * bn
-    if h.shape[0] != n_pad:
-        h = jnp.zeros((n_pad, d), h.dtype).at[:n].set(h)
+    h = pad_rows(h, n_pad)
     y = spmm_sell_kernel(
         sell.tile_rows, sell.tile_cols, sell_tile_blocks(sell), h,
         n_live_block_rows=sell.n_live_block_rows,
         bd=bd or _pick_bd(d), out_dtype=out_dtype, interpret=interpret)
-    y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
+    return unpermute_rows(sell, y)
+
+
+@jax.named_scope("sparse.layout.permute")
+def permute_rows(sell: SellCS, x):
+    """Logical rows of ``x`` gathered into packed row order; padding
+    rows read the appended zero row."""
+    x_ext = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+    return x_ext[sell.perm]
+
+
+@jax.named_scope("sparse.layout.unpermute")
+def unpermute_rows(sell: SellCS, y):
+    """Compact kernel rows back to logical order: the epilogue gather
+    un-permutes rows and reads the appended zero row for the pruned
+    all-zero rows and the padding."""
+    y_ext = jnp.concatenate([y, jnp.zeros((1, y.shape[1]), y.dtype)])
     return y_ext[sell.tile_out_gather]

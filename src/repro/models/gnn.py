@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.paper_gnn import GNNConfig
 from repro.models.layers import _he
 from repro.sparse import (SparseMatrix, fused_graph_attention, matmul,
@@ -91,22 +92,27 @@ class Graph:
 
 def build_graph(adj_dense: np.ndarray, cfg: GNNConfig,
                 normalize: bool = True) -> Graph:
-    """adj_dense: [N, N] 0/1.  GCN normalization Â = D^-1/2 (A+I) D^-1/2."""
-    n = adj_dense.shape[0]
-    a = adj_dense.astype(np.float32)
-    if normalize:
-        a = a + np.eye(n, dtype=np.float32)
-        deg = a.sum(1)
-        dinv = 1.0 / np.sqrt(np.maximum(deg, 1e-12))
-        a = a * dinv[:, None] * dinv[None, :]
-    formats = ("ell", "csr")
-    adj = SparseMatrix.from_dense(a, formats=formats,
-                                  block=(cfg.block_m, cfg.block_n))
-    if adj.stats is not None and adj.stats.sparsity >= 0.99:
-        # hyper-sparse adjacency: also pack SELL-C-σ so dispatch can
-        # route around the Block-ELL padding cliff
-        adj = adj.with_form("sell")
-    return Graph(adj=adj, n_nodes=n)
+    """adj_dense: [N, N] 0/1.  GCN normalization Â = D^-1/2 (A+I) D^-1/2.
+
+    The ``gnn.build_graph`` span covers it all; its self time is the
+    normalisation, its children the ``sparse.stats`` and
+    ``sparse.pack.<form>`` spans of the packing."""
+    with obs.span("gnn.build_graph"):
+        n = adj_dense.shape[0]
+        a = adj_dense.astype(np.float32)
+        if normalize:
+            a = a + np.eye(n, dtype=np.float32)
+            deg = a.sum(1)
+            dinv = 1.0 / np.sqrt(np.maximum(deg, 1e-12))
+            a = a * dinv[:, None] * dinv[None, :]
+        formats = ("ell", "csr")
+        adj = SparseMatrix.from_dense(a, formats=formats,
+                                      block=(cfg.block_m, cfg.block_n))
+        if adj.stats is not None and adj.stats.sparsity >= 0.99:
+            # hyper-sparse adjacency: also pack SELL-C-σ so dispatch can
+            # route around the Block-ELL padding cliff
+            adj = adj.with_form("sell")
+        return Graph(adj=adj, n_nodes=n)
 
 
 def graph_spmm(graph: Graph, h, *, policy: str = "auto", epilogue=None,
@@ -167,18 +173,19 @@ def gcn_forward(params, graph: Graph, x, *, use_blockell: bool = True,
     h = x
     n_layers = len(params["w"])
     for i, w in enumerate(params["w"]):
-        h = h @ w
-        b = biases[i] if biases is not None else None
-        inner = i < n_layers - 1
-        if fuse:
-            h = graph_spmm(graph, h, policy=policy,
-                           epilogue="relu" if inner else None, bias=b)
-        else:
-            h = graph_spmm(graph, h, policy=policy)
-            if b is not None:
-                h = h + b
-            if inner:
-                h = jax.nn.relu(h)
+        with jax.named_scope(f"gnn.layer{i}"):
+            h = h @ w
+            b = biases[i] if biases is not None else None
+            inner = i < n_layers - 1
+            if fuse:
+                h = graph_spmm(graph, h, policy=policy,
+                               epilogue="relu" if inner else None, bias=b)
+            else:
+                h = graph_spmm(graph, h, policy=policy)
+                if b is not None:
+                    h = h + b
+                if inner:
+                    h = jax.nn.relu(h)
     return h
 
 
@@ -256,24 +263,26 @@ def gat_forward(params, graph: Graph, x, *, policy: Optional[str] = None,
     # attention scores ignore the normalized adjacency weights)
     patt = None if fuse else graph.adj.to("csr").pattern()
     for i, w in enumerate(params["w"]):
-        h = h @ w
-        s_src = (h @ params["a_src"][i])[:, 0]  # [N]
-        s_dst = (h @ params["a_dst"][i])[:, 0]
-        # score factors with K=2 (paper §4.4): q=[s_src, 1], k=[1, s_dst]
-        # so (q kᵀ)[i, j] = s_src[i] + s_dst[j]
-        q = jnp.stack([s_src, jnp.ones_like(s_src)], axis=1)  # [N,2]
-        if fuse:
-            k = jnp.stack([jnp.ones_like(s_dst), s_dst], axis=1)  # [N,2]
-            h = fused_graph_attention(graph.adj, q, k, h,
-                                      edge_act="leaky_relu",
-                                      negative_slope=0.2, policy=policy,
-                                      candidates=cand or None)
-        else:
-            c = jnp.stack([jnp.ones_like(s_dst), s_dst], axis=0)  # [2,N]
-            e = sample(patt, q, c, policy=policy).data  # [nnz]
-            e = jax.nn.leaky_relu(e, 0.2)
-            alpha = _segment_softmax(e, graph.row_ids, n)
-            h = matmul(patt.with_data(alpha), h, policy=policy)
-        if i < len(params["w"]) - 1:
-            h = jax.nn.elu(h)
+        with jax.named_scope(f"gnn.layer{i}"):
+            h = h @ w
+            with jax.named_scope("gnn.scores"):
+                s_src = (h @ params["a_src"][i])[:, 0]  # [N]
+                s_dst = (h @ params["a_dst"][i])[:, 0]
+                # score factors with K=2 (paper §4.4): q=[s_src, 1],
+                # k=[1, s_dst] so (q kᵀ)[i, j] = s_src[i] + s_dst[j]
+                q = jnp.stack([s_src, jnp.ones_like(s_src)], axis=1)
+            if fuse:
+                k = jnp.stack([jnp.ones_like(s_dst), s_dst], axis=1)
+                h = fused_graph_attention(graph.adj, q, k, h,
+                                          edge_act="leaky_relu",
+                                          negative_slope=0.2, policy=policy,
+                                          candidates=cand or None)
+            else:
+                c = jnp.stack([jnp.ones_like(s_dst), s_dst], axis=0)
+                e = sample(patt, q, c, policy=policy).data  # [nnz]
+                e = jax.nn.leaky_relu(e, 0.2)
+                alpha = _segment_softmax(e, graph.row_ids, n)
+                h = matmul(patt.with_data(alpha), h, policy=policy)
+            if i < len(params["w"]) - 1:
+                h = jax.nn.elu(h)
     return h

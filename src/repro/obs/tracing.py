@@ -8,6 +8,10 @@ Completed spans land in a bounded ring on the :class:`Tracer` and their
 durations feed the ``span_ms{name=...}`` histogram of the attached
 :class:`~repro.obs.registry.MetricsRegistry`, so the latency breakdown
 is visible both as individual traces and as aggregate percentiles.
+Each span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+so under an active profiler it lands on the trace's host plane, on the
+clock of the device ops it launched; with no profiler running the
+annotation records nothing.
 
 The canonical serve-path span taxonomy (see DESIGN.md "Observability"):
 
@@ -20,6 +24,13 @@ The canonical serve-path span taxonomy (see DESIGN.md "Observability"):
                     the first call of a lane — the sentry separates it)
   serve.complete  — unbatch, trim, future resolution
   train.step      — one optimizer step of ``train_loop``
+
+and of graph set-up:
+
+  gnn.build_graph    — ``models.gnn.build_graph``; its self time is the
+                       normalisation
+  sparse.stats       — host sparsity stats of a dense operand
+  sparse.pack.<form> — packing one carried form (ell, csr, sell, coo)
 """
 from __future__ import annotations
 
@@ -32,6 +43,8 @@ import time
 from typing import Any, Deque, Dict, Iterator, Mapping, Optional, Tuple
 
 import collections
+
+import jax
 
 from repro.obs.registry import MetricsRegistry
 
@@ -105,7 +118,8 @@ class Tracer:
             parent_id=parent.span_id if parent else None)
         stack.append(sp)
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(name):
+                yield sp
         finally:
             stack.pop()
             dur_ms = (time.perf_counter() - sp.t0) * 1e3

@@ -19,8 +19,7 @@ from repro.kernels.fused.epilogue import Epilogue
 from repro.sparse.matrix import FORMATS, SparseMatrix
 from repro.sparse.ops import (available_paths, fused_graph_attention,
                               matmul, sample, sddmm, spmv)
-from repro.sparse.plan import (PlanCache, plan_cache_stats,
-                               reset_plan_cache_stats)
+from repro.sparse.plan import PlanCache, plan_cache_stats
 
 spmm = matmul  # functional alias mirroring the legacy free function
 
@@ -28,5 +27,5 @@ __all__ = [
     "Epilogue", "FORMATS", "SparseMatrix",
     "available_paths", "fused_graph_attention", "matmul", "sample",
     "sddmm", "spmm", "spmv",
-    "PlanCache", "plan_cache_stats", "reset_plan_cache_stats",
+    "PlanCache", "plan_cache_stats",
 ]

@@ -18,6 +18,11 @@ dense) and records its decision in the dispatch log, so the duality is
 observable: after a backward pass ``dispatch_log()`` contains the
 partner op's plan.
 
+Scopes: each path executor names the device ops it emits
+``sparse.<op>.<path>`` and each backward rule ``sparse.vjp.<op>``
+(``jax.named_scope``: HLO metadata only), so a profile ties every op to
+the product, path and rule that issued it.
+
 Gradient semantics: cotangents flow to the *stored values* of the form
 the forward pass read; structural zeros (padding slots, element zeros)
 receive zero gradient so SGD can never resurrect pruned entries.
@@ -50,6 +55,19 @@ Cfg = Tuple[str, bool, bool, Optional[int], Optional[str]]
 EpiCfg = Tuple[str, bool, bool, Optional[int], Optional[str], Epilogue]
 # attention cfg: (path, use_kernel, interpret, act, slope, out_dtype_str)
 AttnCfg = Tuple[str, bool, bool, str, float, Optional[str]]
+
+
+def _dispatch_scope(op: str):
+    """Name every device op a path executor emits ``sparse.<op>.<path>``
+    (``cfg[0]`` is the planned path), so a profile ties each op to the
+    product and path that issued it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(cfg, *args):
+            with jax.named_scope(f"sparse.{op}.{cfg[0]}"):
+                return fn(cfg, *args)
+        return run
+    return wrap
 
 
 def _float0_like(x):
@@ -110,6 +128,7 @@ def form_read_by(a: SparseMatrix, path: str) -> str:
 # ---------------------------------------------------------------------------
 
 
+@_dispatch_scope("spmm")
 def spmm_exec(cfg: Cfg, a: SparseMatrix, h):
     """Run one planned SpMM path; h: [N, D] logical rows; returns [M, D]."""
     path, use_kernel, interpret, bd, out_dtype = cfg
@@ -145,6 +164,7 @@ def spmm_exec(cfg: Cfg, a: SparseMatrix, h):
     raise ValueError(f"unknown spmm path {path!r}")
 
 
+@_dispatch_scope("spmv")
 def spmv_exec(cfg: Cfg, a: SparseMatrix, x):
     """Run one planned SpMV path; x: [N] logical entries; returns [M].
 
@@ -181,6 +201,7 @@ def spmv_exec(cfg: Cfg, a: SparseMatrix, x):
     raise ValueError(f"unknown spmv path {path!r}")
 
 
+@_dispatch_scope("sddmm")
 def sample_exec(cfg: Cfg, a: SparseMatrix, b, c):
     """Raw sampled dots (B @ C at A's stored slots), in the layout of the
     form the path reads — the unweighted SDDMM the backward rules share."""
@@ -247,6 +268,7 @@ def _spmm_fwd(cfg: Cfg, a: SparseMatrix, h):
     return spmm_exec(cfg, a, h), (a, h)
 
 
+@jax.named_scope("sparse.vjp.spmm")
 def _spmm_bwd(cfg: Cfg, res, g):
     path = cfg[0]
     a, h = res
@@ -282,6 +304,7 @@ def _spmv_fwd(cfg: Cfg, a: SparseMatrix, x):
     return spmv_exec(cfg, a, x), (a, x)
 
 
+@jax.named_scope("sparse.vjp.spmv")
 def _spmv_bwd(cfg: Cfg, res, g):
     path = cfg[0]
     a, x = res
@@ -313,6 +336,7 @@ def sddmm_values(cfg: Cfg, a: SparseMatrix, b, c):
     return _sddmm_fwd(cfg, a, b, c)[0]
 
 
+@_dispatch_scope("sddmm")
 def _sddmm_fwd(cfg: Cfg, a: SparseMatrix, b, c):
     raw = sample_exec(cfg, a, b, c)
     form_name = form_read_by(a, cfg[0])
@@ -322,6 +346,7 @@ def _sddmm_fwd(cfg: Cfg, a: SparseMatrix, b, c):
     return out.astype(out_dtype), (a, b, c, raw)
 
 
+@jax.named_scope("sparse.vjp.sddmm")
 def _sddmm_bwd(cfg: Cfg, res, g):
     path = cfg[0]
     a, b, c, raw = res
@@ -355,6 +380,7 @@ sddmm_values.defvjp(_sddmm_fwd, _sddmm_bwd)
 # ---------------------------------------------------------------------------
 
 
+@_dispatch_scope("spmm")
 def spmm_epilogue_exec(cfg: EpiCfg, a: SparseMatrix, h, bias, residual):
     """Run one planned SpMM path with its epilogue fused.
 
@@ -397,6 +423,7 @@ def _spmm_epilogue_fwd(cfg: EpiCfg, a: SparseMatrix, h, bias, residual):
     return out, (a, h, bias, residual, out)
 
 
+@jax.named_scope("sparse.vjp.spmm")
 def _spmm_epilogue_bwd(cfg: EpiCfg, res, g):
     path, use_kernel, interpret = cfg[0], cfg[1], cfg[2]
     epi = cfg[5]
@@ -489,6 +516,7 @@ def _form_row_softmax(a: SparseMatrix, form_name: str, e, mask):
     return ex / jnp.maximum(den[form.rows][:, :, None], EPS)
 
 
+@_dispatch_scope("attention")
 def fused_attention_exec(cfg: AttnCfg, a: SparseMatrix, q, k, v):
     """One-pass SDDMM→edge-act→softmax→SpMM over A's structural nonzeros.
 
@@ -544,6 +572,7 @@ def _fused_attention_fwd(cfg: AttnCfg, a: SparseMatrix, q, k, v):
     return out, (a, q, k, v, out)
 
 
+@jax.named_scope("sparse.vjp.attention")
 def _fused_attention_bwd(cfg: AttnCfg, res, g):
     """The fused pipeline's backward, assembled from the kernel duality.
 

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.formats import CSR, BlockCOO, BlockELL, SellCS
 from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
 from repro.dispatch.policy import PATH_CSR, PATH_SELL
@@ -196,8 +197,10 @@ class SparseMatrix:
         if a.ndim != 2:
             raise ValueError(f"expected a 2-D matrix, got shape {a.shape}")
         bm, bn = block
-        rows, cols = np.nonzero(a)
-        stats = _blocked_stats(a.shape, rows, cols, bm, bn, nnz=len(rows))
+        with obs.span("sparse.stats"):
+            rows, cols = np.nonzero(a)
+            stats = _blocked_stats(a.shape, rows, cols, bm, bn,
+                                   nnz=len(rows))
         if formats is None:
             if format == "auto":
                 pick = CostModel.pick(
@@ -207,22 +210,23 @@ class SparseMatrix:
             formats = (format,)
         forms: Dict[str, Any] = {}
         for name in formats:
-            if name == "ell":
-                forms[name] = BlockELL.from_dense(a, bm, bn,
-                                                  ell_width=ell_width)
-            elif name == "sell":
-                forms[name] = SellCS.from_dense(a, block=block)
-            elif name == "coo":
-                forms[name] = BlockCOO.from_dense(a, bm, bn)
-            elif name == "csr":
-                forms[name] = (
-                    jnp.asarray(rows.astype(np.int32)),
-                    jnp.asarray(cols.astype(np.int32)),
-                    jnp.asarray(a[rows, cols]),
-                )
-            else:
+            if name not in FORMATS:
                 raise ValueError(
                     f"unknown format {name!r}; expected one of {FORMATS}")
+            with obs.span(f"sparse.pack.{name}"):
+                if name == "ell":
+                    forms[name] = BlockELL.from_dense(a, bm, bn,
+                                                      ell_width=ell_width)
+                elif name == "sell":
+                    forms[name] = SellCS.from_dense(a, block=block)
+                elif name == "coo":
+                    forms[name] = BlockCOO.from_dense(a, bm, bn)
+                else:
+                    forms[name] = (
+                        jnp.asarray(rows.astype(np.int32)),
+                        jnp.asarray(cols.astype(np.int32)),
+                        jnp.asarray(a[rows, cols]),
+                    )
         return cls(forms, a.shape, stats)
 
     @classmethod
@@ -365,6 +369,7 @@ class SparseMatrix:
                 f"{self.shape} (stats carry the padded extent)")
         return SparseMatrix(self._forms, self.shape, stats)
 
+    @jax.named_scope("sparse.layout.pattern")
     def pattern(self) -> "SparseMatrix":
         """0/1 mask of the primary form's nonzero entries (the sampling
         operand SDDMM and the backward pass work on)."""
@@ -382,7 +387,8 @@ class SparseMatrix:
         """
         if fmt in self._forms:
             return self
-        converted = self.to(fmt)
+        with obs.span(f"sparse.pack.{fmt}"):
+            converted = self.to(fmt)
         forms = dict(self._forms)
         forms[fmt] = converted._forms[fmt]
         return SparseMatrix(forms, self.shape, self.stats,

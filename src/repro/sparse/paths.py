@@ -19,6 +19,10 @@ Path vocabulary matches the dispatch layer (see dispatch/policy.py):
                 dot SDDMM.  Exact nnz work, no MXU.
   * ``dense`` — densify (device scatter) and run the dense matmul /
                 full-product sample.
+
+The jnp products here name their device ops ``sparse.xla.<fn>`` and the
+layout conversions ``sparse.layout.<what>`` (``jax.named_scope``), so a
+profile separates XLA-lowered product work from layout work.
 """
 from __future__ import annotations
 
@@ -50,6 +54,7 @@ def csr_to_device_arrays(csr: CSR) -> Tuple[Array, Array, Array]:
     )
 
 
+@jax.named_scope("sparse.xla.spmm_elements")
 def spmm_elements(row_ids, col_ids, values, h, num_rows: int):
     """Y = A @ H via gather + segment-sum (element-granular)."""
     gathered = values[:, None].astype(jnp.float32) * h[col_ids].astype(
@@ -59,6 +64,7 @@ def spmm_elements(row_ids, col_ids, values, h, num_rows: int):
     return out.astype(h.dtype)
 
 
+@jax.named_scope("sparse.xla.sddmm_element_dots")
 def sddmm_element_dots(row_ids, col_ids, b, c):
     """out[e] = b[row[e]] . c[:, col[e]] — the per-edge dot products.
 
@@ -69,6 +75,7 @@ def sddmm_element_dots(row_ids, col_ids, b, c):
     return jnp.sum(bs * cs, axis=-1).astype(b.dtype)
 
 
+@jax.named_scope("sparse.xla.sddmm_elements")
 def sddmm_elements(row_ids, col_ids, values, b, c):
     """values ⊙ (B @ C) sampled at the element coordinates."""
     dots = sddmm_element_dots(row_ids, col_ids, b, c)
@@ -86,6 +93,7 @@ def sddmm_elements(row_ids, col_ids, values, b, c):
 # itself, so each layout gets a direct reduction instead.
 
 
+@jax.named_scope("sparse.xla.spmv_elements")
 def spmv_elements(row_ids, col_ids, values, x, num_rows: int):
     """y = A @ x via gather + segment-sum (element-granular)."""
     prod = values.astype(jnp.float32) * x[col_ids].astype(jnp.float32)
@@ -93,6 +101,7 @@ def spmv_elements(row_ids, col_ids, values, x, num_rows: int):
     return out.astype(x.dtype)
 
 
+@jax.named_scope("sparse.xla.spmv_ell")
 def spmv_ell(ell: BlockELL, x, *, out_dtype=None):
     """y = A @ x with A in Block-ELL; x already padded to ell.shape[1].
 
@@ -108,6 +117,7 @@ def spmv_ell(ell: BlockELL, x, *, out_dtype=None):
     return y.reshape(ell.shape[0]).astype(out_dtype)
 
 
+@jax.named_scope("sparse.xla.spmv_coo")
 def spmv_coo(coo: BlockCOO, x, *, out_dtype=None):
     """y = A @ x with A in Block-COO (scatter-add over nonzero blocks)."""
     bm, bn = coo.bm, coo.bn
@@ -120,6 +130,7 @@ def spmv_coo(coo: BlockCOO, x, *, out_dtype=None):
     return out.reshape(coo.shape[0]).astype(out_dtype)
 
 
+@jax.named_scope("sparse.xla.spmv_sell")
 def spmv_sell(sell: SellCS, x, *, out_dtype=None):
     """y = A @ x with A in SELL-C-σ — scatter-free per-bucket reduction.
 
@@ -159,6 +170,7 @@ def spmm_ell(ell: BlockELL, h, *, use_kernel: bool = False,
                          interpret=interpret)
 
 
+@jax.named_scope("sparse.xla.spmm_coo")
 def spmm_coo(coo: BlockCOO, h, *, out_dtype=None):
     """Y = A @ H with A in Block-COO (scatter-add over nonzero blocks).
 
@@ -192,6 +204,7 @@ def sddmm_blocked(coo: BlockCOO, b, c, *, use_kernel: bool = False,
                           interpret=interpret)
 
 
+@jax.named_scope("sparse.layout.ell_to_coo")
 def ell_to_coo(ell: BlockELL) -> BlockCOO:
     """Flatten Block-ELL slots into Block-COO (traceable, no host work).
 
@@ -206,6 +219,7 @@ def ell_to_coo(ell: BlockELL) -> BlockCOO:
     return BlockCOO(rows=rows, cols=cols, blocks=blocks, shape=ell.shape)
 
 
+@jax.named_scope("sparse.layout.transpose")
 def transpose_coo(coo: BlockCOO) -> BlockCOO:
     """A.T in Block-COO: swap coordinates, transpose each block."""
     return BlockCOO(
@@ -221,6 +235,7 @@ def transpose_coo(coo: BlockCOO) -> BlockCOO:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sparse.xla.spmm_sell_ref")
 def spmm_sell_ref(sell: SellCS, h, *, out_dtype=None):
     """Y = A @ H with A in SELL-C-σ — the scatter-free reference.
 
@@ -279,6 +294,7 @@ def sample_sell(sell: SellCS, b, c, *, use_kernel: bool = False,
     return sddmm_element_dots(sell.slot_rows, sell.slot_cols, b, c)
 
 
+@jax.named_scope("sparse.layout.densify")
 def densify_sell(sell: SellCS):
     """Device scatter of the slots (padding slots add zeros)."""
     m, n = sell.shape
@@ -291,11 +307,13 @@ def densify_sell(sell: SellCS):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sparse.layout.densify")
 def densify_elements(row_ids, col_ids, values, shape: Tuple[int, int]):
     m, n = shape
     return jnp.zeros((m, n), values.dtype).at[row_ids, col_ids].add(values)
 
 
+@jax.named_scope("sparse.layout.densify")
 def densify_ell(ell: BlockELL):
     nbr, w, bm, bn = ell.blocks.shape
     nbc = ell.shape[1] // bn
@@ -304,6 +322,7 @@ def densify_ell(ell: BlockELL):
     return out.transpose(0, 2, 1, 3).reshape(ell.shape)
 
 
+@jax.named_scope("sparse.layout.densify")
 def densify_coo(coo: BlockCOO):
     bm, bn = coo.bm, coo.bn
     nbr, nbc = coo.shape[0] // bm, coo.shape[1] // bn
@@ -312,11 +331,13 @@ def densify_coo(coo: BlockCOO):
     return out.transpose(0, 2, 1, 3).reshape(coo.shape)
 
 
+@jax.named_scope("sparse.xla.spmm_dense")
 def spmm_dense(a_dense, h):
     """Dense baseline (the paper's Fig. 2 failure mode)."""
     return a_dense @ h
 
 
+@jax.named_scope("sparse.layout.sample_blocks")
 def sample_blocks(full, rows, cols, bm: int, bn: int):
     """Gather (bm, bn) tiles of a full [M, N] product at block coords."""
     m, n = full.shape
@@ -324,6 +345,7 @@ def sample_blocks(full, rows, cols, bm: int, bn: int):
     return tiles[rows, cols]  # [nnzb, bm, bn]
 
 
+@jax.named_scope("sparse.layout.pad")
 def pad_rows(x, target: int):
     """Zero-pad x's leading dim up to ``target`` (no-op when equal)."""
     if x.shape[0] == target:
@@ -331,6 +353,7 @@ def pad_rows(x, target: int):
     return jnp.zeros((target,) + x.shape[1:], x.dtype).at[: x.shape[0]].set(x)
 
 
+@jax.named_scope("sparse.layout.pad")
 def pad_cols(x, target: int):
     if x.shape[1] == target:
         return x

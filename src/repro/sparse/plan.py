@@ -11,35 +11,23 @@ the matrix's shape/format/stats (the rest of the aux tuple) do.
 
 Each cache also keeps its own hit/miss counters, so per-engine reports
 (two serving engines in one process) never alias each other; the
-module-level counters aggregate across all instances for the benchmark
-harness.
+process-wide totals are the registry counters
+``plan_cache_{hits,misses}_total``.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Dict, Hashable, Optional
 
 from repro import obs
 
-
-@dataclasses.dataclass
-class PlanCacheStats:
-    hits: int = 0
-    misses: int = 0
-
-
-# Process-global counters (all SparseMatrix instances).
-GLOBAL_STATS = PlanCacheStats()
+HITS, MISSES = "plan_cache_hits_total", "plan_cache_misses_total"
 
 
 def plan_cache_stats() -> Dict[str, int]:
-    """Aggregate plan-cache counters across every SparseMatrix."""
-    return {"hits": GLOBAL_STATS.hits, "misses": GLOBAL_STATS.misses}
-
-
-def reset_plan_cache_stats() -> None:
-    GLOBAL_STATS.hits = 0
-    GLOBAL_STATS.misses = 0
+    """Aggregate plan-cache counters across every SparseMatrix (the
+    registry's series; ``obs.reset()`` zeroes them)."""
+    return {"hits": int(obs.REGISTRY.total(HITS)),
+            "misses": int(obs.REGISTRY.total(MISSES))}
 
 
 class PlanCache:
@@ -60,12 +48,10 @@ class PlanCache:
         plan = self.entries.get(key)
         if plan is None:
             self.misses += 1
-            GLOBAL_STATS.misses += 1
-            obs.counter("plan_cache_misses_total").inc()
+            obs.counter(MISSES).inc()
         else:
             self.hits += 1
-            GLOBAL_STATS.hits += 1
-            obs.counter("plan_cache_hits_total").inc()
+            obs.counter(HITS).inc()
         return plan
 
     def put(self, key: Hashable, plan: Any) -> None:
