@@ -413,11 +413,12 @@ class SellCS:
     * ``tile_rows``/``tile_cols``: int32[T] live-tile coordinates
       (compacted block-row, original block-column).
     * ``tile_slot_map``: int32[T, bm, bn] tile cell -> slot id
-      (``n_slots`` for dead cells) — tile data is gathered from
-      ``slot_vals`` so the values live exactly once.
+      (``n_slots`` for dead cells) — the tile structure the SDDMM mask,
+      ``DeltaGraph`` and block-diagonal batches read.
     * ``slot_tile_pos``: int32[n_slots] slot -> flat tile-cell position
-      (``T*bm*bn`` for padding slots) — how SDDMM tile output folds
-      back into slot order.
+      (``T*bm*bn`` for padding and deleted slots) — where tile data is
+      scattered from ``slot_vals`` (so the values live exactly once),
+      and how SDDMM tile output folds back into slot order.
     * ``tile_out_gather``: int32[M] original row -> row of the compact
       kernel output (``n_live*bm`` for pruned rows).
 

@@ -110,7 +110,7 @@ def sample_sell_blocked(sell: SellCS, b, c, *, bk: int | None = None,
     """Raw dots (B @ C) at the live structural slots, in slot order.
 
     ``b``: [M, K] logical rows; ``c``: [K, N] logical columns.  Output:
-    float32[n_slots] — padding slots read the appended zero cell.
+    float32[n_slots] — padding slots read 0.
     """
     from repro.kernels.sddmm.ops import _pick_bk
     from repro.kernels.spmm.sell import permute_rows
@@ -128,7 +128,17 @@ def sample_sell_blocked(sell: SellCS, b, c, *, bk: int | None = None,
     tiles = sddmm_sell_kernel(
         sell.tile_rows, sell.tile_cols, mask, b_perm, c_t,
         bk=bk or _pick_bk(k), out_dtype=jnp.float32, interpret=interpret)
-    with jax.named_scope("sparse.layout.tile_slots"):
-        flat = jnp.concatenate([tiles.reshape(-1),
-                                jnp.zeros((1,), tiles.dtype)])
-        return flat[sell.slot_tile_pos]
+    return tile_slots(sell, tiles)
+
+
+@jax.named_scope("sparse.layout.tile_slots")
+def tile_slots(sell: SellCS, tiles):
+    """Each slot's value read from its cell of the ``[T, bm, bn]`` tile
+    output; padding and deleted slots (position ``T*bm*bn``) read 0.
+
+    The read is indexed by (tile, row, column), so the tile output is
+    neither flattened nor copied: only the slots' cells are touched.
+    """
+    t, ij = jnp.divmod(sell.slot_tile_pos, sell.bm * sell.bn)
+    i, j = jnp.divmod(ij, sell.bn)
+    return tiles.at[t, i, j].get(mode="fill", fill_value=0)

@@ -25,6 +25,7 @@ Grid: (D/bd, T)   [T innermost => sequential accumulate/flush]
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -129,14 +130,19 @@ def spmm_sell_tiles_ref(tile_rows, tile_cols, tile_blocks, h,
 
 @jax.named_scope("sparse.layout.tile_values")
 def sell_tile_blocks(sell: SellCS):
-    """Gather the live-tile data from the slot values (trace-safe).
+    """Scatter the slot values into the live-tile data (trace-safe).
 
-    Values live exactly once (``slot_vals``); dead tile cells map to the
-    appended zero slot.
+    Values live exactly once (``slot_vals``); each slot lands at its
+    ``slot_tile_pos`` cell of a zeroed ``[T, bm, bn]`` buffer, and the
+    padding and deleted slots (position ``T*bm*bn``) are dropped.  The
+    indexed work scales with the slots, not with the tile cells.  The
+    scatter is flat: the TPU compiler rewrites a (tile, row, column)
+    scatter without its scope, and it is no faster on a v5e.
     """
-    vals_ext = jnp.concatenate(
-        [sell.slot_vals, jnp.zeros((1,), sell.slot_vals.dtype)])
-    return vals_ext[sell.tile_slot_map]
+    shape = (sell.n_tiles, sell.bm, sell.bn)
+    cells = jnp.zeros((math.prod(shape),), sell.slot_vals.dtype)
+    return cells.at[sell.slot_tile_pos].set(
+        sell.slot_vals, mode="drop").reshape(shape)
 
 
 def spmm_sell_blocked(sell: SellCS, h, *, bd: int | None = None,

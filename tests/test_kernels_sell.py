@@ -6,14 +6,21 @@ fixed-width Block-ELL kernel never exercises, so the parity sweep leans
 on skewed and pruned structures.  Larger parity cases are slow-marked
 for the scheduled kernel-parity CI job (``--runslow``).
 """
+import dataclasses
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.batch.block_diag import BatchedSparseMatrix
 from repro.core.formats import SellCS
-from repro.kernels.sddmm.sell import sample_sell_blocked
+from repro.kernels.sddmm.sell import sample_sell_blocked, tile_slots
 from repro.kernels.spmm.sell import (sell_tile_blocks, spmm_sell_blocked,
                                      spmm_sell_kernel, spmm_sell_tiles_ref)
+from repro.serve.runtime.delta import DeltaGraph
+from repro.sparse.matrix import SparseMatrix
 from repro.sparse.paths import spmm_sell_ref
 
 
@@ -94,6 +101,153 @@ def test_spmm_sell_empty_matrix():
                             interpret=True)
     assert out.shape == (64, 8)
     assert np.all(np.asarray(out) == 0.0)
+
+
+def _cell_gather(sell):
+    """The tile view's values read cell by cell through
+    ``tile_slot_map``: dead cells read an appended zero slot."""
+    vals_ext = jnp.concatenate(
+        [sell.slot_vals, jnp.zeros((1,), sell.slot_vals.dtype)])
+    return vals_ext[sell.tile_slot_map]
+
+
+def _skewed(rng):
+    dense = np.zeros((256, 256), np.float32)
+    dense[:4] = _rand_sparse(rng, 4, 256, 0.6)
+    dense[100:140] = _rand_sparse(rng, 40, 256, 0.01)
+    dense[255, 255] = 2.0
+    return dense
+
+
+def _block_diag_sell(rng):
+    mats = [SparseMatrix.from_dense(_rand_sparse(rng, m, m, 0.05),
+                                    formats=("sell",), block=(8, 8))
+            for m in (40, 64, 24)]
+    return BatchedSparseMatrix.from_matrices(
+        mats, formats=("sell",)).matrix.form("sell")
+
+
+def _delta_sell(rng):
+    """A SELL overlay after in-place inserts and deletes: inserted slots
+    sit in live tiles, deleted slots point at the dead cell."""
+    dense = _rand_sparse(rng, 64, 64, 0.1)
+    dg = DeltaGraph(dense, form="sell", c=16, block=(8, 8))
+    rows, cols = np.nonzero(dense)
+    inserted = 0
+    for r, c in zip(rows, cols):
+        mate = (c // 8) * 8 + (c + 1) % 8   # same tile, fresh cell
+        if dense[r, mate] == 0 and inserted < 6:
+            dg.insert(int(r), int(mate), 1.5)
+            dense[r, mate] = 1.5
+            inserted += 1
+    for r, c in list(zip(rows, cols))[::7]:
+        dg.delete(int(r), int(c))
+    assert inserted == 6 and dg.repacks == 0
+    return dg.matrix.form("sell")
+
+
+SELL_CASES = {
+    "ragged": lambda rng: SellCS.from_dense(
+        _rand_sparse(rng, 100, 70, 0.05), c=8, block=(4, 4)),
+    "ragged-slack": lambda rng: SellCS.from_dense(
+        _rand_sparse(rng, 100, 70, 0.05), c=8, block=(4, 4),
+        width_slack=2),
+    "rectangular": lambda rng: SellCS.from_dense(
+        _rand_sparse(rng, 256, 128, 0.05), c=4, block=(8, 16)),
+    "rectangular-slack": lambda rng: SellCS.from_dense(
+        _rand_sparse(rng, 256, 128, 0.05), c=4, block=(8, 16),
+        width_slack=2),
+    "skewed": lambda rng: SellCS.from_dense(_skewed(rng), c=8,
+                                            block=(8, 8)),
+    "skewed-slack": lambda rng: SellCS.from_dense(
+        _skewed(rng), c=8, block=(8, 8), width_slack=2),
+    "block-diag": _block_diag_sell,
+    "delta": _delta_sell,
+    "empty": lambda rng: SellCS.from_dense(np.zeros((64, 64), np.float32)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(SELL_CASES))
+def test_sell_tile_blocks_equals_cell_gather(rng, case, dtype):
+    sell = SELL_CASES[case](rng)
+    sell = dataclasses.replace(sell, slot_vals=sell.slot_vals.astype(dtype))
+    got = np.asarray(jax.jit(sell_tile_blocks)(sell))
+    want = np.asarray(jax.jit(_cell_gather)(sell))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+
+
+def _flat_slot_read(sell, tiles):
+    """Each slot's cell read from the flattened tile output with an
+    appended zero cell for the padding slots."""
+    flat = jnp.concatenate([tiles.reshape(-1), jnp.zeros((1,), tiles.dtype)])
+    return flat[sell.slot_tile_pos]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(SELL_CASES))
+def test_tile_slots_equals_flat_slot_read(rng, case, dtype):
+    sell = SELL_CASES[case](rng)
+    tiles = jnp.asarray(rng.normal(size=sell.tile_slot_map.shape), dtype)
+    got = np.asarray(jax.jit(tile_slots)(sell, tiles))
+    want = np.asarray(jax.jit(_flat_slot_read)(sell, tiles))
+    assert got.dtype == want.dtype and got.shape == (sell.n_slots,)
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+
+
+def _large_ops(fn, args, n_elements):
+    """(opcode, shape) of each instruction in ``fn``'s compiled HLO,
+    parameters aside, whose result has at least ``n_elements``."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = []
+    for shape, op in re.findall(r"= \w+\[([\d,]*)\]\S* (\w[\w-]*)\(",
+                                text):
+        size = int(np.prod([int(d) for d in shape.split(",") if d]))
+        if op != "parameter" and size >= n_elements:
+            found.append((op, shape))
+    return found
+
+
+def test_tile_view_reads_and_writes_no_cell_per_tile_cell(rng):
+    """The tile view's values cost one write per slot, and the slots'
+    read of the SDDMM tile output one read per slot: no gather over
+    every cell of every tile, and no flattened copy of the tile output
+    (the detector finds both in the cell-wise forms)."""
+    sell = SellCS.from_dense(_rand_sparse(rng, 128, 128, 0.02), c=8,
+                             block=(16, 16), width_slack=2)
+    n_cells = int(np.prod(sell.tile_slot_map.shape))
+    assert sell.n_slots < n_cells
+    tiles = jnp.asarray(rng.normal(size=sell.tile_slot_map.shape),
+                        jnp.float32)
+
+    def gathers(fn, *args):
+        return [op for op, _ in _large_ops(fn, args, n_cells)
+                if op == "gather"]
+
+    assert gathers(_cell_gather, sell)
+    assert gathers(sell_tile_blocks, sell) == []
+    assert _large_ops(_flat_slot_read, (sell, tiles), n_cells)
+    assert _large_ops(tile_slots, (sell, tiles), n_cells) == []
+
+
+def test_sell_tile_blocks_gradient_reads_each_slot_cell(rng):
+    sell = SellCS.from_dense(_rand_sparse(rng, 100, 70, 0.05), c=8,
+                             block=(4, 4), width_slack=2)
+    w = rng.normal(size=sell.tile_slot_map.shape).astype(np.float32)
+
+    def loss(vals):
+        return jnp.sum(sell_tile_blocks(
+            dataclasses.replace(sell, slot_vals=vals)) * w)
+
+    grad = np.asarray(jax.jit(jax.grad(loss))(sell.slot_vals))
+    pos = np.asarray(sell.slot_tile_pos)
+    live = pos < w.size
+    assert live.any() and not live.all()   # padding slots are present
+    want = np.where(live, w.reshape(-1)[np.minimum(pos, w.size - 1)], 0.0)
+    np.testing.assert_array_equal(grad, want)
 
 
 @pytest.mark.parametrize("density", [0.01, 0.2])
