@@ -22,12 +22,10 @@ D = 2  # paper §4.4: GAT attention-score dimension
 
 def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
         cost_model=None):
-    from repro.dispatch import DEFAULT_COST_MODEL, last_plan
+    from repro.dispatch import last_plan
     from repro.dispatch.dispatcher import dispatch_sddmm
     from repro.sparse import SparseMatrix
     from repro.sparse import sddmm as sparse_sddmm
-
-    cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
 
     ns = [2048, 4096] if quick else [2048, 4096, 8192]
     # sparsities 0.999 / 0.99 / 0.9 / 0.5 — the BENCH_kernels.json axis
@@ -66,7 +64,7 @@ def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
                                             formats=("coo", "csr"))
                 t_disp = time_fn(
                     lambda: sparse_sddmm(A, jb, jc, policy=policy,
-                                         cost_model=cm).data,
+                                         cost_model=cost_model).data,
                     warmup=1, iters=5)
             plan = last_plan("sddmm")
             emit(f"sddmm_n{n}_d{density:g}_dispatch_{policy}_{api}", t_disp,

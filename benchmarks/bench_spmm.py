@@ -43,12 +43,10 @@ def tpu_projection(ell: BlockELL, d: int) -> float:
 
 def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
         cost_model=None):
-    from repro.dispatch import DEFAULT_COST_MODEL, last_plan
+    from repro.dispatch import last_plan
     from repro.dispatch._forms import LazyForms
     from repro.dispatch.dispatcher import dispatch_spmm
     from repro.sparse import SparseMatrix, matmul
-
-    cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
 
     ns = [2048, 4096] if quick else [2048, 4096, 8192, 16384]
     # sparsities 0.999 / 0.99 / 0.9 / 0.5 — the BENCH_kernels.json axis
@@ -103,7 +101,8 @@ def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
             else:
                 A = SparseMatrix.from_dense(dense, formats=("ell", "csr"))
                 t_disp = time_fn(
-                    lambda: matmul(A, jh, policy=policy, cost_model=cm),
+                    lambda: matmul(A, jh, policy=policy,
+                                   cost_model=cost_model),
                     warmup=1, iters=5)
             plan = last_plan("spmm")
             emit(f"spmm_n{n}_d{density:g}_dispatch_{policy}_{api}", t_disp,

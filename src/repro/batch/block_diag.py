@@ -233,6 +233,9 @@ def _combined_stats(mats: Sequence[SparseMatrix],
     # sell slots concatenate exactly; unknown in any part poisons the sum
     sell_known = all(s.sell_stored_elements > 0 or s.nnz == 0
                      for s in stats)
+    # so do live tiles (_concat_sell appends each part's tiles); an
+    # unmeasured part leaves the count 0, priced at its upper bound
+    tiles_known = all(s.sell_live_tiles > 0 or s.nnz == 0 for s in stats)
     return MatrixStats(
         shape=shape,
         nnz=sum(s.nnz for s in stats),
@@ -244,6 +247,8 @@ def _combined_stats(mats: Sequence[SparseMatrix],
         occupancy=occ,
         sell_stored_elements=(sum(s.sell_stored_elements for s in stats)
                               if sell_known else 0),
+        sell_live_tiles=(sum(s.sell_live_tiles for s in stats)
+                         if tiles_known else 0),
     )
 
 

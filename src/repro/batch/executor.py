@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
+from repro.dispatch.cost_model import CostModel
 from repro.dispatch.dispatcher import plan_spmm
 from repro.dispatch.policy import PATH_CSR, PATH_ELL
 from repro.resilience import chaos
@@ -94,7 +94,7 @@ class BucketedExecutor:
                  max_batch: int = 32,
                  max_executors: int = 64,
                  bucketing: BucketingConfig = DEFAULT_BUCKETING,
-                 cost_model: CostModel = DEFAULT_COST_MODEL,
+                 cost_model: Optional[CostModel] = None,
                  ladder: Any = None,
                  jit: bool = True,
                  degrade_after: int = 3):

@@ -379,6 +379,35 @@ def sell_slot_volume(row_nnz: np.ndarray, c: int = SELL_C,
     return int(widths.sum()) * c
 
 
+def sell_tile_count(shape: Tuple[int, int], rows: np.ndarray,
+                    cols: np.ndarray, block: Tuple[int, int],
+                    c: int = SELL_C, sigma: int = SELL_SIGMA) -> int:
+    """Live (bm x bn) tiles of the SELL-C-σ packing of these coordinates:
+    the grid steps the tile-walk kernels take (``SellCS.n_tiles`` of
+    ``SellCS.from_dense`` with the same C, σ and block).
+
+    A tile is a distinct (packed row // bm, col // bn) pair.  The packed
+    row order is ``from_dense``'s: non-empty slices grouped by ascending
+    width, slice order kept within a width.
+    """
+    rows = np.asarray(rows, dtype=np.int64).reshape(-1)
+    cols = np.asarray(cols, dtype=np.int64).reshape(-1)
+    if len(rows) == 0:
+        return 0
+    row_nnz = np.bincount(rows, minlength=int(shape[0]))
+    order, widths = _sell_row_order(row_nnz, c, sigma)
+    live = np.nonzero(widths > 0)[0]
+    slice_pos = np.zeros(len(widths), np.int64)
+    slice_pos[live[np.argsort(widths[live], kind="stable")]] = \
+        np.arange(len(live))
+    lane = np.arange(len(order))
+    packed = np.empty(len(order), np.int64)
+    packed[order] = slice_pos[lane // c] * c + lane % c
+    bm, bn = block
+    keys = (packed[rows] // bm) * _cdiv(int(shape[1]), bn) + cols // bn
+    return len(np.unique(keys))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class SellCS:

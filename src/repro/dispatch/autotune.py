@@ -164,13 +164,22 @@ def calibrate(
     path on synthetic operands across a few sparsity regimes, normalizes
     each timing by the volume that path streams, and expresses it
     relative to the dense path's per-element time — exactly the
-    ``c_ell`` / ``c_sell`` / ``c_csr`` constants, but measured.
+    ``c_ell`` / ``c_sell`` / ``c_csr`` constants, but measured.  Each
+    path runs jitted, as it does inside a model's step.
+
+    The sell path's volume is that of the executor timed (see
+    ``cost_model``): on the kernels, the live tiles' cells, the same
+    MXU tile product as the Block-ELL walk, so its ratios join
+    ``c_ell``'s and ``c_sell`` (the slot executor's) keeps its shipped
+    constant; on the reference, its slots, fitting ``c_sell``.
 
     Returns the tuned :class:`~repro.dispatch.cost_model.CostModel`
     (median across densities; a path with no valid measurement keeps its
     shipped constant).  When ``cache`` is given the model is attached to
     it, so ``AutotuneCache.save``/``load`` persist the calibration.
     """
+    import functools
+
     import numpy as np
 
     import jax.numpy as jnp
@@ -190,19 +199,20 @@ def calibrate(
                          rng.normal(size=(n, n)), 0.0).astype(np.float32)
         a = SparseMatrix.from_dense(dense, formats=("ell", "sell", "csr"))
         stats = a.stats
-        thunks = {
-            p: (lambda p=p: autodiff.spmm_exec(
-                (p, use_kernel, False, None, None), a, h))
-            for p in ("ell", "sell", "csr", "dense")
-        }
+        runs = {p: jax.jit(functools.partial(
+                    autodiff.spmm_exec, (p, use_kernel, False, None, None)))
+                for p in ("ell", "sell", "csr", "dense")}
+        thunks = {p: (lambda f=f: f(a, h)) for p, f in runs.items()}
         t = measure(thunks, warmup=warmup, iters=iters).timings_us
         per_dense = t["dense"] / max(stats.dense_elements * d, 1)
-        streamed = {"ell": stats.stored_elements,
-                    "sell": stats.sell_stored_elements,
-                    "csr": stats.nnz}
-        for p, vol in streamed.items():
+        streamed = [("ell", "ell", stats.stored_elements),
+                    ("csr", "csr", stats.nnz)]
+        streamed.append(("sell", "ell", stats.sell_tile_elements)
+                        if use_kernel else
+                        ("sell", "sell", stats.sell_stored_elements))
+        for p, const, vol in streamed:
             if vol > 0 and per_dense > 0:
-                ratios[p].append((t[p] / (vol * d)) / per_dense)
+                ratios[const].append((t[p] / (vol * d)) / per_dense)
 
     def _tuned(path: str, shipped: float) -> float:
         if not ratios[path]:

@@ -39,7 +39,8 @@ from repro.core.formats import BlockCOO, BlockELL
 from repro.dispatch import autotune as autotune_mod
 from repro.dispatch._forms import LazyForms
 from repro.dispatch.autotune import AutotuneCache, make_key, measure
-from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
+from repro.dispatch.cost_model import (DEFAULT_COST_MODEL,
+                                       DEVICE_COST_MODELS, CostModel)
 from repro.dispatch.policy import (DEFAULT_CONFIG, DispatchConfig, PATHS,
                                    PATH_CSR, PATH_DENSE, PATH_ELL,
                                    PATH_FUSED_ATTN, PATH_SELL, POLICY_AUTO,
@@ -162,6 +163,13 @@ def default_use_kernel(config: DispatchConfig = DEFAULT_CONFIG) -> bool:
     return jax.default_backend() == "tpu"
 
 
+def default_cost_model() -> CostModel:
+    """The cost constants fitted on the running device's kind
+    (``DEVICE_COST_MODELS``), the shipped constants on any other."""
+    return DEVICE_COST_MODELS.get(jax.devices()[0].device_kind,
+                                  DEFAULT_COST_MODEL)
+
+
 # ---------------------------------------------------------------------------
 # Planning (pure decision; usable at trace time from static stats)
 # ---------------------------------------------------------------------------
@@ -172,7 +180,7 @@ def plan_spmm(
     d: int,
     *,
     policy: str = POLICY_AUTO,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
@@ -182,17 +190,20 @@ def plan_spmm(
 
     ``candidates`` restricts the choice to the paths the caller can
     actually execute (e.g. a Graph carries only the ell + csr forms).
+    ``cost_model`` None prices with ``default_cost_model()``.
     """
-    return _plan("spmm", cost_model.spmm_costs(stats, d), stats,
-                 policy=policy, config=config, use_kernel=use_kernel,
-                 interpret=interpret, candidates=candidates)
+    return _plan("spmm",
+                 lambda cm, tiles: cm.spmm_costs(stats, d, sell_tiles=tiles),
+                 stats, cost_model=cost_model, policy=policy, config=config,
+                 use_kernel=use_kernel, interpret=interpret,
+                 candidates=candidates)
 
 
 def plan_spmv(
     stats: MatrixStats,
     *,
     policy: str = POLICY_AUTO,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
@@ -204,11 +215,13 @@ def plan_spmv(
     with no D to amortize the stream over, the scalar paths close most
     of their per-element disadvantage and hyper-sparse operands tip to
     csr much earlier.  A dedicated op tag keeps the dispatch log honest
-    about which front-end ran.
+    about which front-end ran.  SpMV's sell lane (``paths.spmv_sell``)
+    reduces slot by slot on every backend, so it is priced by slots.
     """
-    return _plan("spmv", cost_model.spmm_costs(stats, 1), stats,
-                 policy=policy, config=config, use_kernel=use_kernel,
-                 interpret=interpret, candidates=candidates)
+    return _plan("spmv", lambda cm, _tiles: cm.spmm_costs(stats, 1),
+                 stats, cost_model=cost_model, policy=policy, config=config,
+                 use_kernel=use_kernel, interpret=interpret,
+                 candidates=candidates, sell_tiles=False)
 
 
 def plan_sddmm(
@@ -216,15 +229,17 @@ def plan_sddmm(
     k: int,
     *,
     policy: str = POLICY_AUTO,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
     candidates: Optional[Tuple[str, ...]] = None,
 ) -> Plan:
-    return _plan("sddmm", cost_model.sddmm_costs(stats, k), stats,
-                 policy=policy, config=config, use_kernel=use_kernel,
-                 interpret=interpret, candidates=candidates)
+    return _plan("sddmm",
+                 lambda cm, tiles: cm.sddmm_costs(stats, k, sell_tiles=tiles),
+                 stats, cost_model=cost_model, policy=policy, config=config,
+                 use_kernel=use_kernel, interpret=interpret,
+                 candidates=candidates)
 
 
 def plan_fused_attention(
@@ -233,7 +248,7 @@ def plan_fused_attention(
     d: int,
     *,
     policy: str = POLICY_AUTO,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
@@ -249,25 +264,42 @@ def plan_fused_attention(
     single-stream cost surface.
     """
     plan = _plan(PATH_FUSED_ATTN,
-                 cost_model.fused_attn_costs(stats, k, d), stats,
-                 policy=policy, config=config, use_kernel=use_kernel,
-                 interpret=interpret, candidates=candidates)
+                 lambda cm, tiles: cm.fused_attn_costs(stats, k, d,
+                                                       sell_tiles=tiles),
+                 stats, cost_model=cost_model, policy=policy, config=config,
+                 use_kernel=use_kernel, interpret=interpret,
+                 candidates=candidates)
     return dataclasses.replace(
         plan, fused="attn",
         reason=plan.reason if plan.policy in PATHS
         else f"one-stream fused pricing (k={k}, d={d}): {plan.reason}")
 
 
-def _plan(op, costs, stats, *, policy, config, use_kernel, interpret,
-          candidates=None) -> Plan:
+def _sell_basis(stats: MatrixStats, tiles: bool) -> str:
+    """What the sell price counted, for the plan's reason."""
+    if not tiles:
+        return f"{stats.sell_stored_elements} slots"
+    n = stats.sell_tile_elements // max(stats.block_m * stats.block_n, 1)
+    return f"{n} tiles" if stats.sell_live_tiles > 0 else f"<={n} tiles"
+
+
+def _plan(op, price, stats, *, cost_model, policy, config, use_kernel,
+          interpret, candidates=None, sell_tiles=None) -> Plan:
+    """``price(cost_model, sell_tiles)`` gives the per-path costs; the
+    sell path is priced by its tiles when it runs the Pallas kernel
+    (``sell_tiles`` None: whenever the kernel or interpret mode runs)."""
     policy = normalize_policy(policy)
     if policy == POLICY_AUTOTUNE:
         # pure planning cannot time candidates; be honest about what ran
         policy = POLICY_AUTO
-    if candidates:
-        costs = {p: c for p, c in costs.items() if p in candidates}
     uk = use_kernel if use_kernel is not None \
         else default_use_kernel(config)
+    if sell_tiles is None:
+        sell_tiles = bool(uk or interpret)
+    cm = cost_model if cost_model is not None else default_cost_model()
+    costs = price(cm, sell_tiles)
+    if candidates:
+        costs = {p: c for p, c in costs.items() if p in candidates}
     if policy in (PATH_ELL, PATH_SELL, PATH_CSR, PATH_DENSE):
         if candidates and policy not in candidates:
             raise ValueError(
@@ -277,7 +309,10 @@ def _plan(op, costs, stats, *, policy, config, use_kernel, interpret,
                     stats=stats)
     path = CostModel.pick(costs)
     reason = (f"cost model: {path} cheapest of "
-              + ", ".join(f"{p}={c:.3g}" for p, c in sorted(costs.items())))
+              + ", ".join(f"{p}={c:.3g}"
+                          + (f" ({_sell_basis(stats, sell_tiles)})"
+                             if p == PATH_SELL else "")
+                          for p, c in sorted(costs.items())))
     return Plan(op=op, path=path, policy=policy, reason=reason,
                 use_kernel=uk, interpret=interpret, costs=costs, stats=stats)
 
@@ -350,7 +385,7 @@ def dispatch_spmm(
     interpret: Optional[bool] = None,
     bd: Optional[int] = None,
     out_dtype=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     cache: Optional[AutotuneCache] = None,
 ):
@@ -499,7 +534,7 @@ def dispatch_sddmm(
     interpret: Optional[bool] = None,
     bk: Optional[int] = None,
     out_dtype=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     cache: Optional[AutotuneCache] = None,
 ) -> BlockCOO:

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.core.formats import (BlockCOO, BlockELL, CSR, _cdiv,
                                 blockell_stream_elements,
-                                sell_slot_volume)
+                                sell_slot_volume, sell_tile_count)
 
 
 def _structure_features(shape: Tuple[int, int], rows: np.ndarray,
@@ -61,6 +61,10 @@ class MatrixStats:
     # slots the SELL-C-σ packing would stream (real + slice padding) at
     # the default (C, σ); 0 = not measured (sell path unpriceable)
     sell_stored_elements: int = 0
+    # live (block_m x block_n) tiles of that packing: the grid steps of
+    # the SELL tile-walk kernels; 0 = not measured (priced at the bound
+    # ``sell_tile_elements`` gives)
+    sell_live_tiles: int = 0
     # -- structure features (0 = not measured, e.g. transposed stats) --
     row_nnz_mean: float = 0.0     # nnz per logical row
     row_nnz_cv: float = 0.0       # row-nnz coefficient of variation
@@ -99,6 +103,19 @@ class MatrixStats:
         m_pad = self.n_block_rows * max(self.block_m, 1)
         return max(self.stored_elements, m_pad * self.max_row_nnz)
 
+    @property
+    def sell_tile_elements(self) -> int:
+        """Tile cells the SELL tile-walk kernels multiply: live tiles x
+        block_m x block_n.  Where the tile count was not measured it is
+        bounded above: each slot lies in at most one tile, and no packing
+        has more tiles than block rows x block columns."""
+        tiles = self.sell_live_tiles
+        if tiles <= 0:
+            n_block_cols = _cdiv(int(self.shape[1]), max(self.block_n, 1))
+            tiles = min(self.sell_stored_elements,
+                        self.n_block_rows * n_block_cols)
+        return int(tiles) * self.block_m * self.block_n
+
     def with_capacity(self, capacity: int) -> "MatrixStats":
         """Stats restated at a mutable overlay's **slot capacity**.
 
@@ -109,9 +126,10 @@ class MatrixStats:
         retrace every consumer.  The stable choice is to price the
         overlay at its capacity (live + slack slots): conservative for
         every per-element path, and exactly what the layout streams once
-        tombstones and free slots are counted.  The planner re-prices
-        from exact live stats at repack boundaries (see
-        ``DeltaGraph.exact_stats``).
+        tombstones and free slots are counted.  Inserts can open tiles,
+        so the live tile count is dropped for its upper bound.  The
+        planner re-prices from exact live stats at repack boundaries
+        (see ``DeltaGraph.exact_stats``).
         """
         cap = int(capacity)
         if cap < self.nnz:
@@ -122,7 +140,8 @@ class MatrixStats:
             self, nnz=cap,
             stored_elements=max(self.stored_elements, cap),
             sell_stored_elements=(max(self.sell_stored_elements, cap)
-                                  if self.sell_stored_elements else 0))
+                                  if self.sell_stored_elements else 0),
+            sell_live_tiles=0)
 
     # -- constructors -------------------------------------------------------
 
@@ -156,6 +175,7 @@ class MatrixStats:
             ell_width=width,
             occupancy=len(ub) / max(nbr * width, 1),
             sell_stored_elements=sell_slot_volume(row_nnz),
+            sell_live_tiles=sell_tile_count((m, n), rows, cols, (bm, bn)),
             **_structure_features((m, n), rows, cols, row_nnz),
         )
 
@@ -185,6 +205,8 @@ class MatrixStats:
             ell_width=w,
             occupancy=ell.occupancy(),
             sell_stored_elements=sell_slot_volume(row_nnz),
+            sell_live_tiles=sell_tile_count(ell.shape, grows, gcols,
+                                            (ell.bm, ell.bn)),
             **_structure_features(ell.shape, grows, gcols, row_nnz),
         )
 
@@ -210,6 +232,8 @@ class MatrixStats:
             ell_width=0,
             occupancy=real / max(nnzb, 1),
             sell_stored_elements=sell_slot_volume(row_nnz),
+            sell_live_tiles=sell_tile_count(coo.shape, grows, gcols,
+                                            (coo.bm, coo.bn)),
             **_structure_features(coo.shape, grows, gcols, row_nnz),
         )
 

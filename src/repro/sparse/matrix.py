@@ -40,7 +40,8 @@ import numpy as np
 
 from repro import obs
 from repro.core.formats import CSR, BlockCOO, BlockELL, SellCS
-from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
+from repro.dispatch.cost_model import CostModel
+from repro.dispatch.dispatcher import plan_spmm
 from repro.dispatch.policy import PATH_CSR, PATH_SELL
 from repro.dispatch.stats import MatrixStats
 from repro.sparse import paths
@@ -180,14 +181,16 @@ class SparseMatrix:
                    formats: Optional[Tuple[str, ...]] = None,
                    block: Tuple[int, int] = (64, 64),
                    ell_width: Optional[int] = None,
-                   cost_model: CostModel = DEFAULT_COST_MODEL,
+                   cost_model: Optional[CostModel] = None,
                    ) -> "SparseMatrix":
         """Build from a concrete dense matrix.
 
         ``format="auto"`` measures the operand's blocked structure and
-        picks the element form when the cost model predicts the scalar
-        path wins (hyper-sparsity), the blocked form otherwise.
-        ``formats`` overrides with an explicit multi-form tuple.
+        packs the form of the path the dispatcher would plan for an
+        SpMM on this backend: the element form when the scalar path wins
+        (hyper-sparsity), sell when its executor here is cheapest, the
+        blocked form otherwise.  ``formats`` overrides with an explicit
+        multi-form tuple.
         """
         if _is_traced(a):
             raise TypeError(
@@ -203,8 +206,8 @@ class SparseMatrix:
                                    nnz=len(rows))
         if formats is None:
             if format == "auto":
-                pick = CostModel.pick(
-                    cost_model.spmm_costs(stats, _AUTO_FORMAT_D))
+                pick = plan_spmm(stats, _AUTO_FORMAT_D,
+                                 cost_model=cost_model).path
                 format = {PATH_CSR: "csr", PATH_SELL: "sell"}.get(pick,
                                                                   "ell")
             formats = (format,)

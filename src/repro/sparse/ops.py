@@ -29,7 +29,7 @@ import dataclasses
 
 from repro.dispatch import autotune as autotune_mod
 from repro.dispatch.autotune import AutotuneCache, make_key, measure
-from repro.dispatch.cost_model import DEFAULT_COST_MODEL, CostModel
+from repro.dispatch.cost_model import CostModel
 from repro.dispatch.dispatcher import (Plan, default_use_kernel,
                                        plan_fused_attention, plan_sddmm,
                                        plan_spmm, plan_spmv, record_plan)
@@ -62,7 +62,7 @@ def available_paths(a: SparseMatrix) -> Tuple[str, ...]:
 
 def _resolve_plan(op: str, a: SparseMatrix, inner_dim, ref_dtype,
                   policy: str, cand: Tuple[str, ...], uk: bool,
-                  interpret: bool, cost_model: CostModel,
+                  interpret: bool, cost_model: Optional[CostModel],
                   config: DispatchConfig,
                   autotune_cache: Optional[AutotuneCache],
                   exec_thunk, concrete: bool,
@@ -171,7 +171,7 @@ def matmul(
     epilogue=None,
     bias=None,
     residual=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     autotune_cache: Optional[AutotuneCache] = None,
 ):
@@ -268,7 +268,7 @@ def spmv(
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
     out_dtype=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     autotune_cache: Optional[AutotuneCache] = None,
 ):
@@ -325,7 +325,7 @@ def sddmm(
     interpret: bool = False,
     bk: Optional[int] = None,
     out_dtype=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     autotune_cache: Optional[AutotuneCache] = None,
 ) -> SparseMatrix:
@@ -393,7 +393,7 @@ def fused_graph_attention(
     use_kernel: Optional[bool] = None,
     interpret: bool = False,
     out_dtype=None,
-    cost_model: CostModel = DEFAULT_COST_MODEL,
+    cost_model: Optional[CostModel] = None,
     config: DispatchConfig = DEFAULT_CONFIG,
     autotune_cache: Optional[AutotuneCache] = None,
 ):
