@@ -13,17 +13,15 @@ import numpy as np
 
 from benchmarks.common import emit, time_fn
 from repro.core.formats import BlockCOO
-from repro.core.sddmm import sddmm_coo
 from repro.data.pipeline import random_sparse_dense
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.sparse.paths import sddmm_element_dots
 
 D = 2  # paper §4.4: GAT attention-score dimension
 
 
-def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
-        cost_model=None):
+def run(quick: bool = True, policy: str = "auto", cost_model=None):
     from repro.dispatch import last_plan
-    from repro.dispatch.dispatcher import dispatch_sddmm
     from repro.sparse import SparseMatrix
     from repro.sparse import sddmm as sparse_sddmm
 
@@ -46,28 +44,21 @@ def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
                 return np.where(mask, b @ c, 0.0)
 
             t_cpu = time_fn(dense_sample, warmup=1, iters=3)
-            f = jax.jit(lambda r, cc, bb, ccm: sddmm_coo(r, cc, bb, ccm))
+            f = jax.jit(sddmm_element_dots)
             t_coo = time_fn(f, jr, jcl, jb, jc, warmup=2, iters=5)
             emit(f"sddmm_n{n}_d{density:g}_dense_cpu", t_cpu, "")
             emit(f"sddmm_n{n}_d{density:g}_coo_cpu", t_coo,
                  f"speedup_vs_dense={t_cpu / t_coo:.2f}")
 
             # the dispatch layer's pick under the requested policy
-            if api == "legacy":
-                coo_a = BlockCOO.from_dense(mask.astype(np.float32), 64, 64)
-                t_disp = time_fn(
-                    lambda: dispatch_sddmm(coo_a, jb, jc,
-                                           policy=policy).blocks,
-                    warmup=1, iters=5)
-            else:
-                A = SparseMatrix.from_dense(mask.astype(np.float32),
-                                            formats=("coo", "csr"))
-                t_disp = time_fn(
-                    lambda: sparse_sddmm(A, jb, jc, policy=policy,
-                                         cost_model=cost_model).data,
-                    warmup=1, iters=5)
+            A = SparseMatrix.from_dense(mask.astype(np.float32),
+                                        formats=("coo", "csr"))
+            t_disp = time_fn(
+                lambda: sparse_sddmm(A, jb, jc, policy=policy,
+                                     cost_model=cost_model).data,
+                warmup=1, iters=5)
             plan = last_plan("sddmm")
-            emit(f"sddmm_n{n}_d{density:g}_dispatch_{policy}_{api}", t_disp,
+            emit(f"sddmm_n{n}_d{density:g}_dispatch_{policy}_sparse", t_disp,
                  f"chosen={plan.path};policy={plan.policy}")
 
             # mnz sensitivity: Block-COO tile padding overhead (paper: a
@@ -96,8 +87,5 @@ if __name__ == "__main__":
     ap.add_argument("--policy", default="auto",
                     choices=["auto", "autotune", "ell", "sell", "csr",
                              "dense"])
-    ap.add_argument("--api", default="sparse", choices=["legacy", "sparse"],
-                    help="dispatch surface: legacy free functions or the "
-                         "unified SparseMatrix front-end")
     args = ap.parse_args()
-    run(quick=args.quick, policy=args.policy, api=args.api)
+    run(quick=args.quick, policy=args.policy)

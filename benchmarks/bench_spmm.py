@@ -22,10 +22,10 @@ import scipy.sparse as sp
 
 from benchmarks.common import emit, time_fn
 from repro.core.formats import BlockELL
-from repro.core.spmm import spmm_dense
 from repro.data.pipeline import random_sparse_dense
 from repro.kernels.spmm.ref import spmm_blockell_ref
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.sparse.paths import spmm_dense
 
 D = 256  # paper §4.1
 
@@ -41,11 +41,8 @@ def tpu_projection(ell: BlockELL, d: int) -> float:
     return max(flops / PEAK_FLOPS, bytes_ / HBM_BW)
 
 
-def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
-        cost_model=None):
+def run(quick: bool = True, policy: str = "auto", cost_model=None):
     from repro.dispatch import last_plan
-    from repro.dispatch._forms import LazyForms
-    from repro.dispatch.dispatcher import dispatch_spmm
     from repro.sparse import SparseMatrix, matmul
 
     ns = [2048, 4096] if quick else [2048, 4096, 8192, 16384]
@@ -89,23 +86,15 @@ def run(quick: bool = True, policy: str = "auto", api: str = "sparse",
                  proj * 1e6,
                  f"projected_speedup_vs_cpu_csr={t_csr / (proj * 1e6):.1f}")
 
-            # the dispatch layer's pick under the requested policy —
-            # either the legacy free-function surface or the unified
-            # SparseMatrix front-end (whose steady state is the
-            # plan-cache hit path: plan once, then execute)
-            if api == "legacy":
-                op = LazyForms.from_dense(dense, block_m=64, block_n=64)
-                t_disp = time_fn(
-                    lambda: dispatch_spmm(op, jh, policy=policy),
-                    warmup=1, iters=5)
-            else:
-                A = SparseMatrix.from_dense(dense, formats=("ell", "csr"))
-                t_disp = time_fn(
-                    lambda: matmul(A, jh, policy=policy,
-                                   cost_model=cost_model),
-                    warmup=1, iters=5)
+            # the dispatch layer's pick under the requested policy; the
+            # steady state is the plan-cache hit path (plan once, then
+            # execute)
+            A = SparseMatrix.from_dense(dense, formats=("ell", "csr"))
+            t_disp = time_fn(
+                lambda: matmul(A, jh, policy=policy, cost_model=cost_model),
+                warmup=1, iters=5)
             plan = last_plan("spmm")
-            emit(f"spmm_n{n}_d{density:g}_dispatch_{policy}_{api}", t_disp,
+            emit(f"spmm_n{n}_d{density:g}_dispatch_{policy}_sparse", t_disp,
                  f"chosen={plan.path};policy={plan.policy}")
 
 
@@ -117,8 +106,5 @@ if __name__ == "__main__":
     ap.add_argument("--policy", default="auto",
                     choices=["auto", "autotune", "ell", "sell", "csr",
                              "dense"])
-    ap.add_argument("--api", default="sparse", choices=["legacy", "sparse"],
-                    help="dispatch surface: legacy free functions or the "
-                         "unified SparseMatrix front-end")
     args = ap.parse_args()
-    run(quick=args.quick, policy=args.policy, api=args.api)
+    run(quick=args.quick, policy=args.policy)

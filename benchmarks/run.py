@@ -49,8 +49,6 @@ def main() -> None:
     ap.add_argument("--policy", default="auto",
                     choices=["auto", "autotune", "ell", "sell", "csr",
                              "dense"])
-    ap.add_argument("--api", default="sparse", choices=["legacy", "sparse"],
-                    help="dispatch surface for the spmm/sddmm benches")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the emitted rows as JSON to PATH")
     ap.add_argument("--calibrate", action="store_true",
@@ -83,7 +81,7 @@ def main() -> None:
         "fleet": bench_serve_fleet.run,
     }
     dispatched = {"spmm", "sddmm", "crossover", "serve", "fused", "corpus"}
-    api_axis = {"spmm", "sddmm"}
+    calibrated = {"spmm", "sddmm"}
     only = set(args.only.split(",")) if args.only else None
     if only:
         unknown = only - set(benches)
@@ -124,9 +122,8 @@ def main() -> None:
         if only and name not in only:
             continue
         print(f"# --- {name} ---", file=sys.stderr)
-        if name in api_axis:
-            fn(quick=quick, policy=args.policy, api=args.api,
-               cost_model=cost_model)
+        if name in calibrated:
+            fn(quick=quick, policy=args.policy, cost_model=cost_model)
         elif name in dispatched:
             fn(quick=quick, policy=args.policy)
         else:
@@ -160,7 +157,6 @@ def main() -> None:
                 json.dump({
                     "quick": quick,
                     "policy": args.policy,
-                    "api": args.api,
                     "rows": kernel_rows,
                 }, f, indent=2)
                 f.write("\n")

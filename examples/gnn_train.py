@@ -34,7 +34,7 @@ def main():
     graph = build_graph(adj, GCFG)
     print(f"graph: {args.n} nodes, {int(adj.sum())} edges; "
           f"adjacency {graph.adj} "
-          f"(Block-ELL occupancy {graph.ell.occupancy():.2f})")
+          f"(Block-ELL occupancy {graph.adj.form('ell').occupancy():.2f})")
 
     x = jnp.asarray(rng.normal(size=(args.n, GCFG.in_features))
                     .astype(np.float32))

@@ -368,8 +368,7 @@ class BucketedExecutor:
     # -- reporting ----------------------------------------------------------
 
     def report(self) -> Dict[str, Any]:
-        """Canonical keys (see DESIGN.md "Observability"); the old
-        ``padding`` spelling resolves via a deprecation alias."""
+        """Canonical keys (see DESIGN.md "Observability")."""
         out = {
             "requests": self.requests,
             "calls": self.calls,
@@ -384,4 +383,4 @@ class BucketedExecutor:
         if self._degraded:
             out["degraded"] = sorted(
                 f"{b.label}/d{d}/{f}" for b, d, f in self._degraded)
-        return obs.renamed_keys(out, {"padding": "waste"})
+        return out

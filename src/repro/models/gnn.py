@@ -67,28 +67,6 @@ class Graph:
         (adj,) = children
         return cls(adj=adj, n_nodes=aux[0])
 
-    # -- legacy accessors (pre-SparseMatrix callers) ------------------------
-
-    @property
-    def ell(self):
-        return self.adj.form("ell")
-
-    @property
-    def stats(self):
-        return self.adj.stats if self.adj is not None else None
-
-    @property
-    def row_ids(self):
-        return self.adj.form("csr")[0]
-
-    @property
-    def col_ids(self):
-        return self.adj.form("csr")[1]
-
-    @property
-    def values(self):
-        return self.adj.form("csr")[2]
-
 
 def build_graph(adj_dense: np.ndarray, cfg: GNNConfig,
                 normalize: bool = True) -> Graph:
@@ -153,13 +131,12 @@ def init_gcn(key, cfg: GNNConfig, *, bias: bool = False) -> Dict:
     return params
 
 
-def gcn_forward(params, graph: Graph, x, *, use_blockell: bool = True,
-                policy: Optional[str] = None, fuse: bool = True):
+def gcn_forward(params, graph: Graph, x, *, policy: str = "auto",
+                fuse: bool = True):
     """GCN forward pass.
 
-    ``policy`` (when given) routes each layer's aggregation through the
-    sparsity-adaptive dispatcher ("auto"/"ell"/"csr"); the legacy
-    ``use_blockell`` flag forces the corresponding path otherwise.
+    ``policy`` routes each layer's aggregation through the
+    sparsity-adaptive dispatcher ("auto", or a forced "ell"/"sell"/"csr").
 
     ``fuse=True`` (default) folds each layer's elementwise tail —
     per-layer bias (when the params carry ``"b"``) and the inter-layer
@@ -167,8 +144,6 @@ def gcn_forward(params, graph: Graph, x, *, use_blockell: bool = True,
     never pays a separate full pass.  ``fuse=False`` keeps the unfused
     composition as the oracle.
     """
-    if policy is None:
-        policy = "ell" if use_blockell else "csr"
     biases = params.get("b")
     h = x
     n_layers = len(params["w"])
@@ -281,7 +256,7 @@ def gat_forward(params, graph: Graph, x, *, policy: Optional[str] = None,
                 c = jnp.stack([jnp.ones_like(s_dst), s_dst], axis=0)
                 e = sample(patt, q, c, policy=policy).data  # [nnz]
                 e = jax.nn.leaky_relu(e, 0.2)
-                alpha = _segment_softmax(e, graph.row_ids, n)
+                alpha = _segment_softmax(e, patt.form("csr")[0], n)
                 h = matmul(patt.with_data(alpha), h, policy=policy)
             if i < len(params["w"]) - 1:
                 h = jax.nn.elu(h)

@@ -27,7 +27,6 @@ from __future__ import annotations
 from typing import Any, Dict
 
 from repro.obs.audit import AuditRow, CostAudit, stats_bucket
-from repro.obs.compat import ReportDict, renamed_keys
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry)
 from repro.obs.sentry import RetraceEvent, RetraceSentry, instrumented_jit
 from repro.obs.tracing import SpanRecord, Tracer
@@ -83,9 +82,8 @@ def reset() -> None:
 
 __all__ = [
     "AUDIT", "AuditRow", "CostAudit", "Counter", "Gauge", "Histogram",
-    "MetricsRegistry", "REGISTRY", "ReportDict", "RetraceEvent",
-    "RetraceSentry", "SENTRY", "SpanRecord", "TRACER", "Tracer",
-    "counter", "gauge", "histogram", "instrumented_jit", "renamed_keys",
-    "reset", "snapshot", "span", "stats_bucket", "to_jsonl",
-    "to_prometheus",
+    "MetricsRegistry", "REGISTRY", "RetraceEvent", "RetraceSentry",
+    "SENTRY", "SpanRecord", "TRACER", "Tracer", "counter", "gauge",
+    "histogram", "instrumented_jit", "reset", "snapshot", "span",
+    "stats_bucket", "to_jsonl", "to_prometheus",
 ]

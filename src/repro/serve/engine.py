@@ -646,17 +646,13 @@ class BatchServingEngine:
     # -- reporting ----------------------------------------------------------
 
     def report(self) -> Dict[str, Any]:
-        """Throughput, latency percentiles, compile + padding counters.
-
-        Canonical keys (``p50_ms``/``p99_ms``); the pre-PR-8 spellings
-        (``latency_ms_p50``/``latency_ms_p99``) resolve via deprecation
-        aliases for one cycle.
-        """
+        """Throughput, latency percentiles (``p50_ms``/``p99_ms``),
+        compile + padding counters."""
         lat = np.asarray(self._latencies_ms, np.float64)
         elapsed = ((self._t_last - self._t_first)
                    if (self._t_first is not None
                        and self._t_last is not None) else 0.0)
-        return obs.renamed_keys({
+        return {
             "submitted": self._submitted,
             "completed": self._completed,
             "failed": self._failed,
@@ -670,7 +666,7 @@ class BatchServingEngine:
                 "retry_tokens": self._budget.remaining(),
                 "worker_restarts": self._sup.restarts,
             },
-        }, {"latency_ms_p50": "p50_ms", "latency_ms_p99": "p99_ms"})
+        }
 
 
 def make_prefill_step(cfg: ModelConfig, max_len: int):

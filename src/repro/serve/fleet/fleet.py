@@ -637,7 +637,7 @@ class ServingFleet:
                 "served": ws.served,
                 "inflight": len(self.router.inflight.get(ws.name, ())),
             }
-        return obs.renamed_keys({
+        return {
             "submitted": submitted,
             "completed": completed,
             "failed": failed,
@@ -655,7 +655,7 @@ class ServingFleet:
                 "lanes": {f"{b}/d{d}": owner for (b, d), owner
                           in self.router.lane_owner.items()},
             },
-        }, {"latency_ms_p50": "p50_ms", "latency_ms_p99": "p99_ms"})
+        }
 
 
 __all__ = ["FleetConfig", "ServingFleet"]

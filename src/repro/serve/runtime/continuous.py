@@ -742,9 +742,7 @@ class ContinuousBatchEngine:
     # -- reporting ----------------------------------------------------------
 
     def report(self) -> Dict[str, Any]:
-        """Canonical keys (see DESIGN.md "Observability"); the old
-        ``latency_ms_p50``/``latency_ms_p99`` spellings resolve via
-        deprecation aliases."""
+        """Canonical keys (see DESIGN.md "Observability")."""
         with self._lock:
             lat = np.asarray(self._latencies_ms, np.float64)
             lanes = {}
@@ -757,7 +755,7 @@ class ContinuousBatchEngine:
                                   / max(lane.slot_steps, 1)),
                     "queued": len(lane.queue),
                 }
-            return obs.renamed_keys({
+            return {
                 "submitted": self.submitted,
                 "completed": self.completed,
                 "failed": self.failed,
@@ -775,4 +773,4 @@ class ContinuousBatchEngine:
                     "worker_restarts": (self._sup.restarts
                                         if self._sup is not None else 0),
                 },
-            }, {"latency_ms_p50": "p50_ms", "latency_ms_p99": "p99_ms"})
+            }

@@ -12,8 +12,9 @@ duality (SpMM's backward is SDDMM and vice versa):
     s = sample(A.pattern(), b, c)                   # SDDMM at A's nnz
     g = jax.grad(lambda v: loss(A.with_data(v) @ h))(A.data)
 
+This is the one sparse entry point: ``repro.dispatch`` only plans.
 See DESIGN.md "Public API" for the conversion table, operator
-semantics, gradient rules, and the legacy-surface deprecation timeline.
+semantics and gradient rules.
 """
 from repro.kernels.fused.epilogue import Epilogue
 from repro.sparse.matrix import FORMATS, SparseMatrix
@@ -21,11 +22,9 @@ from repro.sparse.ops import (available_paths, fused_graph_attention,
                               matmul, sample, sddmm, spmv)
 from repro.sparse.plan import PlanCache, plan_cache_stats
 
-spmm = matmul  # functional alias mirroring the legacy free function
-
 __all__ = [
     "Epilogue", "FORMATS", "SparseMatrix",
     "available_paths", "fused_graph_attention", "matmul", "sample",
-    "sddmm", "spmm", "spmv",
+    "sddmm", "spmv",
     "PlanCache", "plan_cache_stats",
 ]

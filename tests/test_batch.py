@@ -208,7 +208,7 @@ def test_executor_trace_count_pin_100_mixed_requests(rng):
     before = ex.compiles
     ex.run(mats[:16], hs[:16])
     assert ex.compiles == before
-    waste = rep["padding"]
+    waste = rep["waste"]
     assert waste["padded_nnz"] >= waste["real_nnz"] > 0
     assert 0.0 <= waste["waste_fraction"] < 1.0
 
@@ -281,11 +281,11 @@ def test_batch_serving_engine_end_to_end(rng, gcn_setup):
         rep = eng.report()
     assert rep["completed"] == rep["submitted"] == 24
     assert rep["req_per_s"] > 0
-    assert rep["latency_ms_p99"] >= rep["latency_ms_p50"] > 0
+    assert rep["p99_ms"] >= rep["p50_ms"] > 0
     assert sum(rep["flushes"].values()) >= 1
     ex = rep["executor"]
     assert ex["compiles"] <= ex["calls"] <= rep["completed"]
-    assert 0.0 <= ex["padding"]["waste_fraction"] < 1.0
+    assert 0.0 <= ex["waste"]["waste_fraction"] < 1.0
 
 
 def test_batch_serving_engine_error_propagates(gcn_setup):

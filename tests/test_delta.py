@@ -24,7 +24,7 @@ import pytest
 
 from repro.dispatch.stats import MatrixStats
 from repro.serve.runtime import DeltaGraph
-from repro.sparse import SparseMatrix, sddmm, spmm
+from repro.sparse import SparseMatrix, matmul, sddmm
 
 BLOCK = (8, 8)
 N = 64
@@ -93,8 +93,8 @@ def test_delta_sequence_matches_rebuild(rng, form, sparsity):
                                rtol=0, atol=0)
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
     rebuild = SparseMatrix.from_dense(final, formats=(form,), block=BLOCK)
-    got = spmm(dg.matrix, h, policy=form)
-    want = spmm(rebuild, h, policy=form)
+    got = matmul(dg.matrix, h, policy=form)
+    want = matmul(rebuild, h, policy=form)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
     assert dg.live_nnz == int((final != 0).sum())
@@ -122,7 +122,7 @@ def test_delete_all_is_zero(rng):
     assert dg.live_nnz == 0
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
     np.testing.assert_array_equal(
-        np.asarray(spmm(dg.matrix, h, policy="csr")), np.zeros((N, D)))
+        np.asarray(matmul(dg.matrix, h, policy="csr")), np.zeros((N, D)))
 
 
 # ---------------------------------------------------------------------------

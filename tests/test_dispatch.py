@@ -12,17 +12,18 @@ import numpy as np
 import pytest
 
 from repro.core.formats import BlockCOO, BlockELL
-from repro.core.sddmm import sddmm
-from repro.core.spmm import spmm
 from repro.dispatch import (AutotuneCache, CostModel, MatrixStats,
-                            SparseOperand, last_plan, normalize_policy,
-                            plan_sddmm, plan_spmm, sparsity_bucket)
+                            clear_log, dispatch_log, last_plan,
+                            normalize_policy, plan_spmm, sparsity_bucket)
 from repro.dispatch.autotune import make_key
-from repro.dispatch.dispatcher import dispatch_sddmm, dispatch_spmm
+from repro.sparse import SparseMatrix
+from repro.sparse.ops import matmul, sddmm
 
 SWEEP = [0.5, 0.9, 0.99, 0.999]
 N, D = 512, 64
 BLOCK = 4  # small blocks keep block-granularity honest at uniform sparsity
+# the original three paths' forms: no sell packing carried
+FORMS = ("ell", "csr")
 
 
 def _uniform_sparse(rng, n, sparsity):
@@ -36,8 +37,8 @@ def sweep_operands():
     out = {}
     for s in SWEEP:
         dense = _uniform_sparse(rng, N, s)
-        out[s] = (dense, SparseOperand.from_dense(
-            dense, block_m=BLOCK, block_n=BLOCK))
+        out[s] = (dense, SparseMatrix.from_dense(
+            dense, formats=FORMS, block=(BLOCK, BLOCK)))
     return out
 
 
@@ -52,7 +53,7 @@ def test_spmm_paths_match_dense_oracle(sweep_operands, path, sparsity):
     dense, op = sweep_operands[sparsity]
     rng = np.random.default_rng(7)
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
-    y = spmm(op, h, policy=path)
+    y = matmul(op, h, policy=path)
     np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
                                rtol=2e-4, atol=2e-4)
     assert last_plan("spmm").path == path
@@ -65,7 +66,8 @@ def test_spmm_kernel_path_interpret_matches_oracle(sparsity):
     dense = _uniform_sparse(rng, 256, sparsity)
     ell = BlockELL.from_dense(dense, 64, 128)
     h = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    y = spmm(ell, h, policy="ell", use_kernel=True, interpret=True)
+    y = matmul(SparseMatrix.from_blockell(ell), h, policy="ell",
+               use_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
                                rtol=5e-4, atol=5e-4)
     plan = last_plan("spmm")
@@ -78,12 +80,13 @@ def test_sddmm_paths_match_dense_oracle(path, sparsity):
     rng = np.random.default_rng(11)
     n, k = 256, 2
     mask = (rng.random((n, n)) < (1.0 - sparsity)).astype(np.float32)
-    coo = BlockCOO.from_dense(mask, 16, 16)
+    a = SparseMatrix.from_blockcoo(BlockCOO.from_dense(mask, 16, 16)) \
+        .with_form("csr")
     b = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
-    out = sddmm(coo, b, c, policy=path)
+    out = sddmm(a, b, c, policy=path)
     oracle = mask * (np.asarray(b) @ np.asarray(c))
-    np.testing.assert_allclose(out.to_dense()[:n, :n], oracle,
+    np.testing.assert_allclose(out.to_dense(), oracle,
                                rtol=2e-4, atol=2e-4)
     assert last_plan("sddmm").path == path
 
@@ -92,22 +95,23 @@ def test_sddmm_kernel_path_interpret_matches_oracle():
     rng = np.random.default_rng(5)
     n, k = 256, 128
     mask = (rng.random((n, n)) < 0.1).astype(np.float32)
-    coo = BlockCOO.from_dense(mask, 64, 64)
+    a = SparseMatrix.from_blockcoo(BlockCOO.from_dense(mask, 64, 64))
     b = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
-    out = sddmm(coo, b, c, policy="ell", use_kernel=True, interpret=True)
+    out = sddmm(a, b, c, policy="ell", use_kernel=True, interpret=True)
     oracle = mask * (np.asarray(b) @ np.asarray(c))
-    np.testing.assert_allclose(out.to_dense()[:n, :n], oracle,
+    np.testing.assert_allclose(out.to_dense(), oracle,
                                rtol=1e-3, atol=1e-3)
 
 
 # ---------------------------------------------------------------------------
-# (b) the crossover: ELL at 90% sparsity, CSR at >=99% (legacy paths),
-#     SELL taking over the hyper-sparse side when its form is carried
+# (b) the crossover: ELL at 90% sparsity, CSR at >=99% (the original
+#     three paths), SELL taking over the hyper-sparse side when its form
+#     is carried
 # ---------------------------------------------------------------------------
 
 
-# among the legacy-executable paths (no sell packing carried)
+# among the original three paths (no sell packing carried)
 EXPECTED_PATH = {0.5: "dense", 0.9: "ell", 0.99: "csr", 0.999: "csr"}
 # with every path priceable, SELL-C-σ owns the hyper-sparse side
 EXPECTED_PATH_FULL = {0.5: "dense", 0.9: "ell", 0.99: "sell",
@@ -119,20 +123,20 @@ def test_cost_model_reproduces_paper_crossover(sweep_operands, sparsity):
     """The paper's crossover among the three original paths is intact;
     unrestricted, the sell path replaces csr past the padding cliff."""
     _, op = sweep_operands[sparsity]
-    legacy = plan_spmm(op.stats(), D, policy="auto",
-                       candidates=("ell", "csr", "dense"))
-    assert legacy.path == EXPECTED_PATH[sparsity], legacy.describe()
-    full = plan_spmm(op.stats(), D, policy="auto")
+    three = plan_spmm(op.stats, D, policy="auto",
+                      candidates=("ell", "csr", "dense"))
+    assert three.path == EXPECTED_PATH[sparsity], three.describe()
+    full = plan_spmm(op.stats, D, policy="auto")
     assert full.path == EXPECTED_PATH_FULL[sparsity], full.describe()
 
 
 @pytest.mark.parametrize("sparsity", SWEEP)
 def test_spmm_auto_dispatch_switches_paths(sweep_operands, sparsity):
-    """spmm(..., policy="auto") routes ELL at 90%, CSR at >=99%."""
+    """matmul(..., policy="auto") routes ELL at 90%, CSR at >=99%."""
     dense, op = sweep_operands[sparsity]
     rng = np.random.default_rng(9)
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
-    y = spmm(op, h, policy="auto")
+    y = matmul(op, h, policy="auto")
     plan = last_plan("spmm")
     assert plan.path == EXPECTED_PATH[sparsity], plan.describe()
     np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
@@ -146,8 +150,9 @@ def test_sddmm_auto_dispatch_crossover():
     c = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
     for sparsity, expected in ((0.9, "ell"), (0.999, "csr")):
         mask = (rng.random((n, n)) < (1.0 - sparsity)).astype(np.float32)
-        coo = BlockCOO.from_dense(mask, 4, 4)
-        sddmm(coo, b, c, policy="auto")
+        a = SparseMatrix.from_blockcoo(BlockCOO.from_dense(mask, 4, 4)) \
+            .with_form("csr")
+        sddmm(a, b, c, policy="auto")
         plan = last_plan("sddmm")
         assert plan.path == expected, plan.describe()
 
@@ -156,7 +161,7 @@ def test_padded_stream_blowup_drives_the_crossover(sweep_operands):
     """The mechanism, not just the outcome: the blow-up is monotone in
     sparsity and crosses c_csr/c_ell between 0.9 and 0.99."""
     cm = CostModel()
-    blowups = [sweep_operands[s][1].stats().padded_stream_blowup
+    blowups = [sweep_operands[s][1].stats.padded_stream_blowup
                for s in SWEEP]
     assert blowups == sorted(blowups)
     ratio = cm.c_csr / cm.c_ell
@@ -169,8 +174,6 @@ def test_padded_stream_blowup_drives_the_crossover(sweep_operands):
 
 
 def _sell_capable(dense):
-    from repro.sparse import SparseMatrix
-
     return SparseMatrix.from_dense(dense, formats=("ell", "csr", "sell"),
                                    block=(BLOCK, BLOCK))
 
@@ -182,9 +185,6 @@ def _sell_capable(dense):
 ])
 def test_auto_routes_sell_past_the_cliff(sparsity, expected):
     """policy=auto picks sell at >=99.5% sparsity, ell at 90%."""
-    from repro.dispatch.dispatcher import clear_log, dispatch_log
-    from repro.sparse import matmul
-
     rng = np.random.default_rng(51)
     dense = _uniform_sparse(rng, N, sparsity)
     op = _sell_capable(dense)
@@ -206,8 +206,6 @@ def test_auto_routes_sell_past_the_cliff(sparsity, expected):
 
 @pytest.mark.parametrize("sparsity", [0.9, 0.995])
 def test_sell_dispatch_log_records_predicted_cost_sddmm(sparsity):
-    from repro.sparse import SparseMatrix, sddmm
-
     rng = np.random.default_rng(53)
     mask = (rng.random((N, N)) < (1.0 - sparsity)).astype(np.float32)
     op = SparseMatrix.from_dense(mask, formats=("coo", "csr", "sell"),
@@ -223,8 +221,6 @@ def test_sell_dispatch_log_records_predicted_cost_sddmm(sparsity):
 
 def test_sell_not_a_candidate_without_the_form():
     """A matrix that never packed sell cannot be routed to it."""
-    from repro.sparse import SparseMatrix, matmul
-
     rng = np.random.default_rng(57)
     dense = _uniform_sparse(rng, 128, 0.999)
     op = SparseMatrix.from_dense(dense, formats=("ell", "csr"),
@@ -238,8 +234,6 @@ def test_sell_not_a_candidate_without_the_form():
 
 def test_with_form_makes_sell_routable():
     """Lazy conversion: adding the sell form turns the path on."""
-    from repro.sparse import SparseMatrix, matmul
-
     rng = np.random.default_rng(59)
     dense = _uniform_sparse(rng, 256, 0.995)
     op = SparseMatrix.from_dense(dense, formats=("ell", "csr"),
@@ -268,24 +262,30 @@ def test_policy_normalization_and_errors():
 def test_forced_policy_outside_candidates_raises(sweep_operands):
     _, op = sweep_operands[0.9]
     with pytest.raises(ValueError):
-        plan_spmm(op.stats(), D, policy="dense", candidates=("ell", "csr"))
+        plan_spmm(op.stats, D, policy="dense", candidates=("ell", "csr"))
 
 
-def test_explicit_kernel_args_force_ell_path(sweep_operands):
-    """Legacy spmm(ell, h, use_kernel=False) semantics survive dispatch."""
+def test_forced_ell_use_kernel_false_runs_reference(sweep_operands):
+    """An explicit use_kernel=False runs the forced blocked path on its
+    jnp reference, and the logged plan says so."""
     dense, op = sweep_operands[0.999]  # auto would pick csr here
     rng = np.random.default_rng(17)
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
-    spmm(op, h, use_kernel=False)
-    assert last_plan("spmm").path == "ell"
+    y = matmul(op, h, policy="ell", use_kernel=False)
+    plan = last_plan("spmm")
+    assert plan.path == "ell" and plan.use_kernel is False
+    np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
+                               rtol=2e-4, atol=2e-4)
 
 
-def test_dispatch_spmm_accepts_blockell_and_dense():
+def test_matmul_accepts_blockell_and_dense():
     rng = np.random.default_rng(19)
     dense = _uniform_sparse(rng, 128, 0.9)
     h = jnp.asarray(rng.normal(size=(128, 32)).astype(np.float32))
-    y1 = dispatch_spmm(BlockELL.from_dense(dense, 16, 16), h, policy="ell")
-    y2 = dispatch_spmm(dense, h, policy="csr")
+    ell = SparseMatrix.from_blockell(BlockELL.from_dense(dense, 16, 16))
+    y1 = matmul(ell, h, policy="ell")
+    y2 = matmul(SparseMatrix.from_dense(dense, formats=("csr",)), h,
+                policy="csr")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=2e-4, atol=2e-4)
 
@@ -294,7 +294,9 @@ def test_dispatch_spmm_accepts_blockell_and_dense():
 def test_spmm_mismatched_h_rows_raises(path):
     """H with the wrong row count must raise, not silently pad/truncate."""
     with pytest.raises(ValueError, match="60 rows but A has 64"):
-        spmm(np.eye(64, dtype=np.float32), jnp.ones((60, 4)), policy=path)
+        matmul(SparseMatrix.from_dense(np.eye(64, dtype=np.float32),
+                                       formats=FORMS),
+               jnp.ones((60, 4)), policy=path)
 
 
 def test_spmm_non_divisible_shapes_trim_correctly():
@@ -304,10 +306,10 @@ def test_spmm_non_divisible_shapes_trim_correctly():
     m, n, d = 100, 70, 16
     mask = rng.random((m, n)) < 0.1
     dense = np.where(mask, rng.normal(size=(m, n)), 0.0).astype(np.float32)
-    op = SparseOperand.from_dense(dense, block_m=16, block_n=16)
+    op = SparseMatrix.from_dense(dense, formats=FORMS, block=(16, 16))
     h = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     for path in ("ell", "csr", "dense"):
-        y = spmm(op, h, policy=path)
+        y = matmul(op, h, policy=path)
         assert y.shape == (m, d)
         np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
                                    rtol=2e-4, atol=2e-4)
@@ -320,20 +322,26 @@ def test_sddmm_non_divisible_shapes_all_paths(path):
     mask = (rng.random((100, 100)) < 0.5).astype(np.float32)
     b = rng.normal(size=(100, 2)).astype(np.float32)
     c = rng.normal(size=(2, 100)).astype(np.float32)
-    out = sddmm(mask, jnp.asarray(b), jnp.asarray(c), policy=path)
-    np.testing.assert_allclose(out.to_dense()[:100, :100],
+    a = SparseMatrix.from_dense(mask, formats=("coo", "csr"))
+    out = sddmm(a, jnp.asarray(b), jnp.asarray(c), policy=path)
+    np.testing.assert_allclose(out.to_dense(),
                                mask * (b @ c), rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("path", ["ell", "csr", "dense", "auto"])
 def test_spmm_1d_h_all_paths(path):
+    """A 1-D H takes the SpMV lane: one plan with op "spmv"."""
     rng = np.random.default_rng(37)
     dense = np.where(rng.random((64, 64)) < 0.1, 1.0, 0.0) \
         .astype(np.float32)
     hv = rng.normal(size=64).astype(np.float32)
-    op = SparseOperand.from_dense(dense, block_m=4, block_n=4)
-    y = spmm(op, jnp.asarray(hv), policy=path)
+    op = SparseMatrix.from_dense(dense, formats=FORMS, block=(4, 4))
+    y = matmul(op, jnp.asarray(hv), policy=path)
     assert y.shape == (64,)
+    plan = last_plan()
+    assert plan.op == "spmv", plan.describe()
+    if path != "auto":
+        assert plan.path == path
     np.testing.assert_allclose(np.asarray(y), dense @ hv,
                                rtol=2e-4, atol=2e-4)
 
@@ -341,23 +349,29 @@ def test_spmm_1d_h_all_paths(path):
 def test_pure_plan_never_claims_autotune(sweep_operands):
     """plan_* cannot time candidates, so the plan must not say it did."""
     _, op = sweep_operands[0.9]
-    plan = plan_spmm(op.stats(), D, policy="autotune")
+    plan = plan_spmm(op.stats, D, policy="autotune")
     assert plan.policy == "auto" and plan.timings_us is None
 
 
-def test_traced_operand_forced_host_policy_raises():
-    """Under jit a forced csr/dense policy must raise, not silently run
-    the blocked path."""
+@pytest.mark.parametrize("path", ["ell", "sell", "csr", "dense"])
+def test_sparse_matrix_under_jit_forced_policy_matches_oracle(path):
+    """A SparseMatrix crosses jax.jit as a pytree: the forced path plans
+    once, at trace time, from the static stats it carries."""
     rng = np.random.default_rng(41)
     dense = _uniform_sparse(rng, 64, 0.9)
-    ell = BlockELL.from_dense(dense, 16, 16)
-    h = jnp.asarray(rng.normal(size=(64, 8)).astype(np.float32))
-
-    ok = jax.jit(lambda e, hh: spmm(e, hh, policy="auto"))(ell, h)
-    np.testing.assert_allclose(np.asarray(ok), dense @ np.asarray(h),
-                               rtol=2e-4, atol=2e-4)
-    with pytest.raises(TypeError, match="traced"):
-        jax.jit(lambda e, hh: spmm(e, hh, policy="csr"))(ell, h)
+    a = SparseMatrix.from_dense(dense, formats=("ell", "csr", "sell"),
+                                block=(16, 16))
+    f = jax.jit(lambda m, hh: matmul(m, hh, policy=path))
+    clear_log()
+    for seed in (0, 1):
+        h = jnp.asarray(np.random.default_rng(seed).normal(size=(64, 8))
+                        .astype(np.float32))
+        np.testing.assert_allclose(np.asarray(f(a, h)),
+                                   dense @ np.asarray(h),
+                                   rtol=2e-4, atol=2e-4)
+    plans = [p for p in dispatch_log() if p.op == "spmm"]
+    assert len(plans) == 1  # traced once, not planned per call
+    assert plans[0].path == path and plans[0].stats == a.stats
 
 
 def test_graph_without_stats_raises_clearly():
@@ -385,16 +399,19 @@ def test_autotune_caches_per_sparsity_bucket(sweep_operands, tmp_path):
     rng = np.random.default_rng(29)
     h = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
     cache = AutotuneCache()
-    y = dispatch_spmm(op, h, policy="autotune", cache=cache)
+    y = matmul(op, h, policy="autotune", autotune_cache=cache)
     np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(h),
                                rtol=2e-4, atol=2e-4)
     assert len(cache) == 1
     first = last_plan("spmm")
     assert first.timings_us and len(first.timings_us) == 3
 
-    # second dispatch in the same bucket: cache hit, no re-measurement
+    # second matrix in the same bucket (its own plan memo): cache hit,
+    # no re-measurement
     misses = cache.misses
-    dispatch_spmm(op, h, policy="autotune", cache=cache)
+    again = SparseMatrix.from_dense(dense, formats=FORMS,
+                                    block=(BLOCK, BLOCK))
+    matmul(again, h, policy="autotune", autotune_cache=cache)
     assert cache.misses == misses
     assert "cached" in last_plan("spmm").reason
 
@@ -404,8 +421,7 @@ def test_autotune_caches_per_sparsity_bucket(sweep_operands, tmp_path):
     cache2 = AutotuneCache()
     cache2.load(str(p))
     assert len(cache2) == 1
-    key = make_key("spmm", op.stats().shape, D, h.dtype,
-                   op.stats().density)
+    key = make_key("spmm", op.stats.shape, D, h.dtype, op.stats.density)
     assert cache2.get(key).path == first.path
 
 
@@ -431,7 +447,7 @@ def test_gcn_policy_paths_agree():
     rng = np.random.default_rng(0)
     adj = random_graph(48, avg_degree=4, seed=1, clustered=False)
     g = build_graph(adj, GCFG)
-    assert isinstance(g.stats, MatrixStats) and g.stats.nnz > 0
+    assert isinstance(g.adj.stats, MatrixStats) and g.adj.stats.nnz > 0
     params = init_gcn(jax.random.PRNGKey(0), GCFG)
     x = jnp.asarray(rng.normal(size=(48, GCFG.in_features))
                     .astype(np.float32))

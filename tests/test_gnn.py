@@ -20,8 +20,8 @@ def test_gcn_blockell_equals_csr_path(rng, graph):
     params = init_gcn(jax.random.PRNGKey(0), GCFG)
     x = jnp.asarray(rng.normal(size=(graph.n_nodes, GCFG.in_features))
                     .astype(np.float32))
-    out_ell = gcn_forward(params, graph, x, use_blockell=True)
-    out_csr = gcn_forward(params, graph, x, use_blockell=False)
+    out_ell = gcn_forward(params, graph, x, policy="ell")
+    out_csr = gcn_forward(params, graph, x, policy="csr")
     np.testing.assert_allclose(np.asarray(out_ell), np.asarray(out_csr),
                                rtol=2e-4, atol=2e-4)
     assert out_ell.shape == (graph.n_nodes, GCFG.n_classes)
@@ -31,7 +31,7 @@ def test_gcn_matches_dense_aggregation(rng, graph):
     params = init_gcn(jax.random.PRNGKey(0), GCFG)
     x = jnp.asarray(rng.normal(size=(graph.n_nodes, GCFG.in_features))
                     .astype(np.float32))
-    a_hat = graph.ell.to_dense()[:graph.n_nodes, :graph.n_nodes]
+    a_hat = graph.adj.form("ell").to_dense()[:graph.n_nodes, :graph.n_nodes]
     h = np.asarray(x)
     for i, w in enumerate(params["w"]):
         h = a_hat @ (h @ np.asarray(w))

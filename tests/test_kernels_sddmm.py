@@ -5,9 +5,9 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.formats import BlockCOO
-from repro.core.sddmm import sddmm_coo
 from repro.kernels.sddmm.ops import sddmm_blockcoo
 from repro.kernels.sddmm.ref import sddmm_blockcoo_ref
+from repro.sparse.paths import sddmm_element_dots
 
 
 @pytest.mark.parametrize("m,n,k,bm,bn,bk", [
@@ -60,8 +60,8 @@ def test_sddmm_coo_elementwise_small_k(rng):
     rows, cols = np.nonzero(mask)
     b = rng.normal(size=(m, 2)).astype(np.float32)
     c = rng.normal(size=(2, n)).astype(np.float32)
-    vals = sddmm_coo(jnp.asarray(rows), jnp.asarray(cols),
-                     jnp.asarray(b), jnp.asarray(c))
+    vals = sddmm_element_dots(jnp.asarray(rows), jnp.asarray(cols),
+                              jnp.asarray(b), jnp.asarray(c))
     expected = (b @ c)[rows, cols]
     np.testing.assert_allclose(np.asarray(vals), expected, rtol=2e-4,
                                atol=2e-4)

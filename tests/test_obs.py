@@ -12,17 +12,15 @@ Pins the contracts the obs layer exports to the rest of the repo:
   * the cost-model audit (stats buckets, predicted-vs-measured rows,
     misprediction detection);
   * the thread-safe bounded dispatch ring log;
-  * the deprecation shim for renamed report keys, and the **schema
-    pins** for every ``report()`` and for ``obs.snapshot()`` — these
-    are the keys dashboards consume; renaming one is a breaking change
-    that must show up here;
+  * the **schema pins** for every ``report()`` and for
+    ``obs.snapshot()`` — these are the keys dashboards consume;
+    renaming one is a breaking change that must show up here;
   * the one-snapshot acceptance contract: a single adaptive serving
     run surfaces dispatcher plan counts, per-lane compiles/calls,
     padding waste, latency histograms, and audit rows.
 """
 import json
 import threading
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +30,6 @@ import pytest
 from repro import obs
 from repro.batch.executor import BucketedExecutor, ExecutorKey
 from repro.obs.audit import CostAudit, stats_bucket
-from repro.obs.compat import renamed_keys
 from repro.obs.registry import MetricsRegistry
 from repro.obs.sentry import RetraceSentry, instrumented_jit
 from repro.obs.tracing import Tracer
@@ -310,32 +307,6 @@ def test_dispatch_log_concurrent_records():
 
 
 # ---------------------------------------------------------------------------
-# Deprecation shim (satellite 2)
-# ---------------------------------------------------------------------------
-
-
-def test_renamed_keys_alias_warns_and_canonical_is_silent():
-    rep = renamed_keys({"p50_ms": 1.0, "other": 2},
-                       {"latency_ms_p50": "p50_ms"})
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert rep["p50_ms"] == 1.0            # canonical: no warning
-        assert rep["other"] == 2
-    with pytest.warns(DeprecationWarning, match="latency_ms_p50"):
-        assert rep["latency_ms_p50"] == 1.0
-    with pytest.warns(DeprecationWarning):
-        assert rep.get("latency_ms_p50") == 1.0
-    assert "latency_ms_p50" in rep and "p50_ms" in rep
-    # json serialization sees only canonical keys
-    assert "latency_ms_p50" not in json.loads(json.dumps(rep))
-
-
-def test_renamed_keys_rejects_dangling_alias():
-    with pytest.raises(KeyError):
-        renamed_keys({"a": 1}, {"old_b": "b"})
-
-
-# ---------------------------------------------------------------------------
 # Report schema pins (satellite 3)
 # ---------------------------------------------------------------------------
 
@@ -360,8 +331,6 @@ def test_executor_report_schema(rng):
     assert {"real_rows", "padded_rows", "real_nnz", "padded_nnz",
             "row_blowup", "nnz_blowup",
             "waste_fraction"} <= set(rep["waste"])
-    with pytest.warns(DeprecationWarning):
-        assert rep["padding"] is rep["waste"]
 
 
 def test_engine_reports_use_canonical_latency_keys(rng):
@@ -376,8 +345,6 @@ def test_engine_reports_use_canonical_latency_keys(rng):
         [f.result(timeout=60) for f in futs]
         rep = eng.report()
     assert {"completed", "p50_ms", "p99_ms", "executor"} <= set(rep)
-    with pytest.warns(DeprecationWarning):
-        assert rep["latency_ms_p50"] == rep["p50_ms"]
 
     with ContinuousBatchEngine(cfg=ContinuousConfig(
             slots=2, adaptive=False, max_wait_ms=0.0)) as ceng:
@@ -387,8 +354,47 @@ def test_engine_reports_use_canonical_latency_keys(rng):
         rep = ceng.report()
     assert {"submitted", "completed", "p50_ms", "p99_ms", "lanes",
             "executor"} <= set(rep)
-    with pytest.warns(DeprecationWarning):
-        assert rep["latency_ms_p99"] == rep["p99_ms"]
+
+
+def _bucketed_report(mats, hs):
+    ex = BucketedExecutor(policy="csr")
+    ex.run(mats, hs)
+    return ex.report()
+
+
+def _batch_engine_report(mats, hs):
+    from repro.serve.engine import BatchServeConfig, BatchServingEngine
+
+    with BatchServingEngine(
+            scfg=BatchServeConfig(max_batch=4, adaptive=True)) as eng:
+        futs = [eng.submit(m, h) for m, h in zip(mats, hs)]
+        eng.drain()
+        [f.result(timeout=60) for f in futs]
+        return eng.report()
+
+
+def _continuous_engine_report(mats, hs):
+    from repro.serve.runtime import ContinuousBatchEngine, ContinuousConfig
+
+    with ContinuousBatchEngine(cfg=ContinuousConfig(
+            slots=2, adaptive=False, max_wait_ms=0.0)) as eng:
+        futs = [eng.submit(m, h) for m, h in zip(mats, hs)]
+        eng.drain()
+        [f.result(timeout=60) for f in futs]
+        return eng.report()
+
+
+@pytest.mark.parametrize("make_report", [
+    _bucketed_report, _batch_engine_report, _continuous_engine_report,
+], ids=["BucketedExecutor", "BatchServingEngine", "ContinuousBatchEngine"])
+def test_report_is_plain_json_dict(rng, make_report):
+    """Reports are plain dicts of canonical keys: what a dashboard reads
+    is what json.dumps writes."""
+    mats, hs = _requests(rng, (32, 48, 32))
+    rep = make_report(mats, hs)
+    assert type(rep) is dict
+    assert json.loads(json.dumps(rep)) == rep
+    assert not {"padding", "latency_ms_p50", "latency_ms_p99"} & set(rep)
 
 
 def test_ladder_and_delta_report_schemas(rng):

@@ -223,7 +223,7 @@ def test_continuous_adaptive_ladder_feeds_executor(rng):
         rep = eng.report()["executor"]
         assert rep["ladder"]["fitted"]
         # post-fit traffic lands on a learned rung, not a geometric step
-        assert any(k.startswith("r112x") for k in rep["padding"]
+        assert any(k.startswith("r112x") for k in rep["waste"]
                    .get("per_bucket", {}))
 
 
@@ -234,7 +234,7 @@ def test_per_bucket_waste_sums_to_aggregate(rng):
             h = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
             eng.submit(mat, h)
         eng.drain()
-        padding = eng.report()["executor"]["padding"]
+        padding = eng.report()["executor"]["waste"]
         per = padding["per_bucket"]
         assert len(per) >= 2
         for field in ("real_rows", "padded_rows", "real_nnz", "padded_nnz"):

@@ -2,9 +2,7 @@
 behavior, plan caching, and the SpMM <-> SDDMM gradient duality.
 
 This file must stay clean under ``-W error::DeprecationWarning`` (CI
-runs it that way): everything here goes through the new surface, so a
-regression that routes in-repo code back through the deprecated free
-functions fails loudly.
+runs it that way).
 """
 import jax
 import jax.numpy as jnp
@@ -443,41 +441,6 @@ def test_grad_through_gat_attention(rng):
     flat = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(l)).all() for l in flat)
     assert any(float(jnp.abs(l).sum()) > 0 for l in flat)
-
-
-# ---------------------------------------------------------------------------
-# deprecated surfaces still work but warn
-# ---------------------------------------------------------------------------
-
-
-def test_legacy_spmm_warns_and_forwards(operands, h):
-    from repro.core.spmm import spmm
-
-    with pytest.warns(DeprecationWarning, match="repro.sparse"):
-        y = spmm(operands[0.9], h, policy="csr")
-    np.testing.assert_allclose(np.asarray(y),
-                               operands[0.9] @ np.asarray(h),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_legacy_sddmm_warns_and_forwards(operands, rng):
-    from repro.core.sddmm import sddmm as legacy_sddmm
-
-    mask = (operands[0.9] != 0).astype(np.float32)
-    b = jnp.asarray(rng.normal(size=(N, 2)).astype(np.float32))
-    c = jnp.asarray(rng.normal(size=(2, N)).astype(np.float32))
-    with pytest.warns(DeprecationWarning, match="repro.sparse"):
-        out = legacy_sddmm(mask, b, c, policy="csr")
-    np.testing.assert_allclose(out.to_dense()[:N, :N],
-                               mask * np.asarray(b @ c),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_legacy_operand_warns(operands):
-    from repro.dispatch import SparseOperand
-
-    with pytest.warns(DeprecationWarning, match="SparseMatrix"):
-        SparseOperand.from_dense(operands[0.9])
 
 
 def test_sell_kernel_route_grads_match_dense(operands, h):
